@@ -45,7 +45,6 @@ _CHILD = textwrap.dedent("""
     from repro.core.hypergraph import Caps
     from repro.core.partitioner import partition
     from repro.dist.sharding import Plan
-    from repro.models import common
     from repro.utils import segops
 
     n_dev = int(sys.argv[1])
@@ -93,8 +92,8 @@ _CHILD = textwrap.dedent("""
             striped_in=True, striped_out=True)
         return (*ks_, *ps_)
 
-    f = jax.jit(common.shard_map(body, mesh=mesh, in_specs=(P(),) * 4,
-                                 out_specs=(P("model"),) * 4))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 4,
+                              out_specs=(P("model"),) * 4, check_vma=False))
     jax.block_until_ready(f(ka, kb, ks, pv))
     t0 = time.perf_counter()
     jax.block_until_ready(f(ka, kb, ks, pv))
@@ -119,7 +118,8 @@ def run() -> list[str]:
 
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
-    env = dict(os.environ, PYTHONPATH=src)
+    # forced host devices: the children never open an accelerator
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out, base = [], None
     for n in DEVICE_COUNTS:

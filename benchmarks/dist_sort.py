@@ -34,7 +34,6 @@ _CHILD = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.models import common
     from repro.utils import segops
 
     n_dev = int(sys.argv[1])
@@ -61,9 +60,9 @@ _CHILD = textwrap.dedent("""
 
     out = {}
     for name, body in (("dist", dist_body), ("gather", gath_body)):
-        f = jax.jit(common.shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P(),) * 4,
-            out_specs=(P("model"),) * 4 + (P(),)))
+            out_specs=(P("model"),) * 4 + (P(),), check_vma=False))
         r = f(*cols, pay)
         jax.block_until_ready(r)
         t0 = time.perf_counter()
@@ -81,7 +80,8 @@ def run() -> list[str]:
 
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
-    env = dict(os.environ, PYTHONPATH=src)
+    # forced host devices: the children never open an accelerator
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = []
     for n in DEVICE_COUNTS:

@@ -57,6 +57,8 @@ def main():
 
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    # the dry run compiles on forced host devices, never on an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     t00 = time.time()
     for i, (arch, shape) in enumerate(todo):
         out_json = os.path.join(OUT, f"{arch}__{shape}.json")

@@ -60,6 +60,7 @@ def main(argv=None) -> None:
         from repro.obs import metrics as obs_metrics
         from repro.obs import trace as obs_trace
     print("name,us_per_call,derived")
+    failed = []
     for key in want:
         if args.metrics_json:
             # reset the global registry + span tree so the lane's snapshot
@@ -70,8 +71,9 @@ def main(argv=None) -> None:
         try:
             for line in mods[key].run():
                 print(line, flush=True)
-        except Exception as e:  # keep the harness going; report the failure
+        except Exception as e:  # report the row, run the other lanes
             print(f"{key}/ERROR,0.0,{type(e).__name__}: {e}", flush=True)
+            failed.append(key)
         print(f"{key}/_elapsed,{(time.time()-t0)*1e6:.0f},", flush=True)
         if args.metrics_json:
             lanes[key] = dict(ts=time.time(),
@@ -80,6 +82,8 @@ def main(argv=None) -> None:
     if args.metrics_json:
         with open(args.metrics_json, "w") as f:
             json.dump(dict(lanes=lanes), f, indent=2, sort_keys=True)
+    if failed:
+        raise SystemExit(f"lanes failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

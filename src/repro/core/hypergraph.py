@@ -597,8 +597,8 @@ def device_pair_count(edge_off: jax.Array) -> jax.Array:
 
 
 def shrink_device(d: DeviceHypergraph, caps: Caps) -> tuple[DeviceHypergraph, Caps]:
-    """Perf iteration P1 (EXPERIMENTS.md §Perf): re-bucket capacities to the
-    next power of two above the live sizes between coarsening levels.
+    """Re-bucket capacities to the next power of two above the live sizes
+    between coarsening levels.
 
     Baseline keeps level-0 capacities for every level (one jit signature,
     but each level pays O(caps) work on mostly-dead lanes). Bucketing trades

@@ -6,8 +6,8 @@ coarsening halts early (paper: < 4096 coarse nodes, empirically stable for
 small k) and a robust initial k-way partitioning is computed on the coarse
 graph. The paper delegates that step to Mt-KaHyPar's direct k-way mode
 (tens of ms on CPU, included in timings); offline we implement a greedy
-affinity + least-load placement on the (tiny) coarsest graph instead —
-documented as a deviation in DESIGN.md. Uncoarsening + refinement then run
+affinity + least-load placement on the (tiny) coarsest graph instead, a
+deviation from the paper. Uncoarsening + refinement then run
 exactly as in the constrained mode with K = k.
 """
 from __future__ import annotations

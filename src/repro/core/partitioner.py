@@ -369,9 +369,9 @@ def partition(hg: HostHypergraph, omega: int, delta: int,
               collect_stats: bool = False) -> PartitionResult:
     """Full multi-level constrained partitioning (paper's SNN mode).
 
-    bucket=True enables pow2 capacity re-bucketing between levels (perf
-    iteration P1; see EXPERIMENTS.md §Perf) — identical results, coarse
-    levels run on geometrically shrinking arrays.
+    bucket=True enables pow2 capacity re-bucketing between levels
+    (`hypergraph.shrink_device`) — identical results, coarse levels run on
+    geometrically shrinking arrays.
 
     plan (a `repro.dist.Plan`) routes the whole V-cycle onto the mesh:
     every coarsening level runs through `dist.partition.coarsen_level` /

@@ -542,12 +542,14 @@ def refine_step_impl(d: DeviceHypergraph, parts: jax.Array,
             jnp.sum(apply_mask.astype(jnp.int32)), kernel_taken, pins_taken)
 
 
-@partial(jax.jit, static_argnames=("caps", "kcap", "params", "enforce_size"))
+@partial(jax.jit, static_argnames=("caps", "kcap", "params"))
 def refine_step(d: DeviceHypergraph, parts: jax.Array, n_parts: jax.Array,
                 caps: Caps, kcap: int, params: RefineParams,
-                enforce_size: bool):
+                enforce_size):
+    """One jitted repetition. ``enforce_size`` is traced, so both halves of
+    a level's theta repetitions share one executable."""
     return refine_step_impl(d, parts, n_parts, caps, kcap, params,
-                            jnp.asarray(enforce_size))
+                            jnp.asarray(enforce_size, bool))
 
 
 def refine_level(d: DeviceHypergraph, parts: jax.Array, n_parts,

@@ -62,7 +62,6 @@ from repro.core.hypergraph import (Caps, DeviceHypergraph, GraphDelta,
                                    check_fits_caps, host_from_device,
                                    packed_host_arrays)
 from repro.dist.sharding import Plan
-from repro.models import common
 
 # the pins-sized storage arrays that stripe over "model"; everything else
 # in DeviceHypergraph is O(N)/O(E) or scalar and stays replicated
@@ -187,9 +186,9 @@ def _stripe_scatter(mesh, per: int):
         lp = jnp.where((pos >= lo) & (pos < lo + per), pos - lo, per)
         return stripe.at[lp].set(val, mode="drop")
 
-    fn = common.shard_map(body, mesh=mesh,
-                          in_specs=(P("model"), P(), P()),
-                          out_specs=P("model"))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P("model"), P(), P()),
+                       out_specs=P("model"), check_vma=False)
     return jax.jit(fn)
 
 

@@ -116,7 +116,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.coarsen import CoarsenParams, coarsen_step_impl
 from repro.core.contract import contract_impl
@@ -124,7 +124,6 @@ from repro.core.hypergraph import Caps
 from repro.core.refine import RefineParams, refine_step_impl
 from repro.dist.graph import ShardedHypergraph, graph_pspecs
 from repro.dist.sharding import Plan
-from repro.models import common
 from repro.utils import segops
 
 
@@ -197,9 +196,9 @@ def _build_step(mesh, data_axis: str, model_axis: str | None, nshards: int,
         pt_out = jax.lax.psum(jnp.where(win, pt, 0), data_axis)
         return parts_out, gains[best], nmv_out, kt_out, pt_out
 
-    fn = common.shard_map(body, mesh=mesh,
-                          in_specs=(graph_pspecs(striped), P(), P(), P(), P()),
-                          out_specs=(P(), P(), P(), P(), P()))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(graph_pspecs(striped), P(), P(), P(), P()),
+                       out_specs=(P(), P(), P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -227,6 +226,9 @@ def refine_level(d, parts, n_parts, caps: Caps, kcap: int,
     step = _build_step(plan.mesh, data_axis, model_axis, nshards,
                        caps, kcap, params, bool(race), striped)
     n_parts = jnp.asarray(n_parts, jnp.int32)
+    # replicated on the mesh, as every repetition returns it: an
+    # uncommitted first input would compile the step a second time
+    parts = jax.device_put(parts, NamedSharding(plan.mesh, P()))
     key = jax.random.PRNGKey(seed)
     hits = jnp.int32(0)
     phits = jnp.int32(0)
@@ -259,8 +261,8 @@ def _build_coarsen_step(mesh, model_axis: str | None, nshards: int,
         return (match, n_pairs, props.n_pairs_live, props.n_nbr_entries,
                 props.kernel_path_taken)
 
-    fn = common.shard_map(body, mesh=mesh, in_specs=(graph_pspecs(striped),),
-                          out_specs=(P(), P(), P(), P(), P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(graph_pspecs(striped),),
+                       out_specs=(P(), P(), P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -273,9 +275,9 @@ def _build_contract(mesh, model_axis: str | None, nshards: int, caps: Caps,
     def body(d, match):
         return contract_impl(d, match, caps, ctx)
 
-    fn = common.shard_map(body, mesh=mesh,
-                          in_specs=(graph_pspecs(striped), P()),
-                          out_specs=(graph_pspecs(striped), P()))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(graph_pspecs(striped), P()),
+                       out_specs=(graph_pspecs(striped), P()), check_vma=False)
     return jax.jit(fn)
 
 

@@ -24,7 +24,7 @@ import dataclasses
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 from repro.models import common
 
@@ -42,6 +42,14 @@ class Plan:
         """Standard 2D (+pod) plan: DP over every non-"model" axis, megatron
         TP over "model", FSDP (params over DP) when `fsdp`."""
         names = tuple(mesh.axis_names)
+        if isinstance(mesh, Mesh):
+            # the rules are GSPMD constraints (parameter shardings,
+            # `constraint`), so the axes are Auto. jax.make_mesh's default
+            # Explicit axes type every op's output sharding instead: a row
+            # gather from an FSDP table by data-sharded ids would then map
+            # "data" to two dims of its result and fail.
+            mesh = Mesh(mesh.devices, names,
+                        axis_types=(AxisType.Auto,) * len(names))
         dp_axes = tuple(a for a in names if a != "model")
         dp = dp_axes[0] if len(dp_axes) == 1 else dp_axes
         tp = "model" if "model" in names else None

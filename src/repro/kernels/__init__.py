@@ -13,18 +13,19 @@ is the TPU-native redesign of the corresponding CUDA kernel:
                 inter() counter accumulated from a dst-flag plane in the
                 same pass (the paper's in-histogram constraint tracking).
   gains       — warp-per-node gain loops over the
-                pins matrix                         -> scalar-prefetch grid:
-                the incidence list is prefetched and drives the BlockSpec
-                index_map that streams pins-matrix columns from HBM.
-  flash_attn  — framework-side hot spot (EXPERIMENTS.md SPerf M-series):
-                online-softmax attention with the score block and running
-                max/denominator resident in VMEM, grid-accumulated over
-                key chunks; HBM traffic collapses to q/k/v/o.
+                pins matrix                         -> DMA gather: each
+                8-node tile reads its incidence rows from SMEM and DMAs the
+                pins-matrix row of every live incident edge from HBM.
+  flash_attn  — framework-side hot spot: online-softmax attention with
+                the score block and running max/denominator resident in
+                VMEM, grid-accumulated over key chunks; HBM traffic
+                collapses to q/k/v/o.
 
 Each subpackage ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper + padding/layout glue) and ref.py (pure-jnp oracle). All kernels
-validate in interpret mode on CPU; tests sweep shapes and dtypes against
-the oracles.
+wrapper + padding/layout glue) and ref.py (pure-jnp oracle). Tests sweep
+shapes against the oracles in interpret mode on CPU, and
+``tests/test_tpu_compile.py`` compiles the partitioner kernels for a v5e
+at full-size tile shapes.
 
 Dispatch contract
 -----------------
@@ -64,41 +65,25 @@ parity contract of ``dist.partition`` relies on.
 Interpret policy
 ----------------
 
-``pallas_interpret()`` below decides compiled-vs-interpret per trace:
-compiled on any accelerator backend (TPU/GPU), interpret only when no
-accelerator is present (CPU has no compiled Pallas path). The
-``REPRO_PALLAS_INTERPRET`` env var overrides: ``1`` forces interpret
-everywhere (debugging on accelerators), ``0`` asserts the compiled path on
-accelerators and is a documented no-op on CPU. The policy is read at trace
-time, so flip it before the first kernel call of a process (jit caches
-traces).
+``pallas_interpret()`` below decides compiled-vs-interpret per trace from
+the backend alone: compiled on TPU, interpret on CPU (which has no
+compiled Pallas path). Any other backend is an error: the kernels are
+written for the TPU, and nothing here may run them elsewhere unnoticed.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-
-_ACCEL_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 
 
 def pallas_interpret() -> bool:
-    """Whether pallas_call should run in interpret mode for this process.
-
-    Default: interpret only when no accelerator backend is present —
-    ``jax.default_backend()`` in ``("tpu", "gpu", "cuda", "rocm")`` compiles
-    (the old ``backend != "tpu"`` policy silently paid interpret-mode
-    overhead on every GPU kernel call). ``REPRO_PALLAS_INTERPRET=1`` forces
-    interpret mode everywhere; ``=0`` requests the compiled path, which on
-    CPU still degrades to interpret (jax raises "Only interpret mode is
-    supported on CPU backend" otherwise), so host CI can exercise both
-    override values safely. Evaluated at trace time — set the env var
-    before the first kernel call of the process.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-    accel = jax.default_backend() in _ACCEL_BACKENDS
-    if env not in ("", None):
-        if env in ("0", "false", "False"):
-            return not accel  # CPU has no compiled Pallas path
+    """Whether pallas_call runs in interpret mode for this process: False
+    on TPU, True on CPU. Raises on any other backend. Evaluated at trace
+    time."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
         return True
-    return not accel
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"backend {backend!r} is neither")

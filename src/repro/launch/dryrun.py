@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract roofline terms from the compiled artifact.
 
@@ -14,6 +11,7 @@ fails here. Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -21,10 +19,12 @@ import time
 import jax
 import numpy as np
 
-# TPU v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12    # bf16 FLOP/s
-HBM_BW = 819e9         # B/s
-ICI_BW = 50e9          # B/s per link
+# Per-chip peaks keyed by jax's ``device_kind``. Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s ICI per chip (4 links, so 50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": dict(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
 
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 _DTYPE_BYTES = {"f64": 8, "s64": 8, "u64": 8, "f32": 4, "s32": 4, "u32": 4,
@@ -155,8 +155,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<0.5: per-device list of dicts
-        cost = cost[0] if cost else {}
     mem = {}
     try:
         ma = compiled.memory_analysis()
@@ -181,9 +179,10 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     cfg = get_config(arch)
     mf = model_flops(cfg, shape)
-    compute_s = flops_total / PEAK_FLOPS
-    memory_s = bytes_total / HBM_BW
-    coll_s = coll["total_bytes"] / ICI_BW
+    pk = PEAKS["TPU v5 lite"]   # the production meshes are v5e pods
+    compute_s = flops_total / pk["flops"]
+    memory_s = bytes_total / pk["hbm_bw"]
+    coll_s = coll["total_bytes"] / pk["ici_bw"]
     dominant = max(("compute", compute_s), ("memory", memory_s),
                    ("collective", coll_s), key=lambda kv: kv[1])[0]
     return dict(
@@ -224,6 +223,9 @@ def main(argv=None):
     ap.add_argument("--q-chunk", type=int, default=0)
     ap.add_argument("--k-chunk", type=int, default=0)
     args = ap.parse_args(argv)
+    # the production meshes need 512 devices: forced host devices, set
+    # before the first backend use in this process
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     from repro.configs.registry import SHAPES, list_configs
     if args.list:

@@ -95,6 +95,9 @@ def main(argv=None):
 
     from repro.core import generate
     from repro.core.partitioner import partition, repartition
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.graph == "snn":
         hg = generate.snn_layered(n_layers=5, width=max(args.nodes // 5, 4),

@@ -61,6 +61,9 @@ def main(argv=None):
     from repro.core.generate import random_kuniform
     from repro.launch.partition import build_plan
     from repro.serve import PartitionService
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     plan = build_plan(args.replicas) if args.mesh == "host" else None
     # the CLI joins the process-global registry so one --metrics-json dump
@@ -119,6 +122,7 @@ def main(argv=None):
     if args.json:
         with open(args.json, "w") as f:
             json.dump(out, f, indent=2)
+    return out
 
 
 if __name__ == "__main__":

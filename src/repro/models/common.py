@@ -97,17 +97,6 @@ def shardings(tree, rules: dict[str, Any], mesh: Mesh):
         lambda s: NamedSharding(mesh, _resolve_pspec(s, rules, mesh)), tree)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check=False):
-    """Version-portable shard_map: `jax.shard_map` (new API, check_vma)
-    when present, else `jax.experimental.shard_map` (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
-
-
 def param_count(tree) -> int:
     return sum(int(np.prod(s.shape))
                for s in jax.tree_util.tree_leaves(tree, is_leaf=is_spec))

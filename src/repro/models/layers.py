@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models.common import (Spec, apply_rope, rms_norm, shard_map,
+from repro.models.common import (Spec, apply_rope, rms_norm,
                                  swiglu)
 
 F32 = jnp.float32
@@ -158,11 +158,11 @@ def decode_attention_seqsharded(plan, q, kcache, vcache, length=None):
         o = jax.lax.psum(o, "model") / jnp.maximum(l, 1e-30)[..., None]
         return o.reshape(B, 1, H, Dh).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp), P(dp, "model"), P(dp, "model")),
         out_specs=P(dp),
-        check=False)(q, kcache, vcache)
+        check_vma=False)(q, kcache, vcache)
 
 
 # ---------------------------------------------------------------------------
@@ -371,9 +371,9 @@ def moe_apply_local_dispatch(p, x, cfg: ArchConfig,
 
     assert B % ndp == 0, "local dispatch requires DP-divisible batch"
     b_loc = B // ndp
-    xe, aux, meta = shard_map(
+    xe, aux, meta = jax.shard_map(
         local, mesh=plan.mesh, in_specs=(P(dp), P()),
-        out_specs=(P(None, dp), P(), P(dp)), check=False)(x, router)
+        out_specs=(P(None, dp), P(), P(dp)), check_vma=False)(x, router)
     # re-shard once for the expert GEMMs: experts -> EP, capacity -> DP
     xe = plan.constraint(xe, "experts", "batch", None)
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["we_gate"])) * \
@@ -382,10 +382,10 @@ def moe_apply_local_dispatch(p, x, cfg: ArchConfig,
     ye = jnp.einsum("ecf,efd->ecd", h, p["we_down"])
     ye = plan.constraint(ye, "experts", "batch", None)
 
-    out = shard_map(
+    out = jax.shard_map(
         lambda yb, mb: combine(yb, mb, b_loc, S, D),
         mesh=plan.mesh, in_specs=(P(None, dp), P(dp)),
-        out_specs=P(dp), check=False)(ye.astype(x.dtype), meta)
+        out_specs=P(dp), check_vma=False)(ye.astype(x.dtype), meta)
     if mo.n_shared:
         out = out + mlp_apply(p["shared"], x, plan)
     return out, aux

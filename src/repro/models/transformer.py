@@ -25,7 +25,7 @@ from repro.configs.base import ArchConfig, LayerSpec
 from repro.models import layers as L
 from repro.models import ssm
 from repro.models.common import (Spec, apply_rope, rms_norm, layer_norm,
-                                 shard_map, stack_specs,
+                                 stack_specs,
                                  softmax_cross_entropy)
 
 F32 = jnp.float32
@@ -304,12 +304,12 @@ def _decode_attn_update(plan, q, k_new, v_new, kcache, vcache, pos):
         o = jax.lax.psum(o, "model") / jnp.maximum(l, 1e-30)[..., None]
         return o.reshape(B, 1, H, Dh).astype(qb.dtype), kb, vb
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp), P(dp), P(dp), P(dp, "model"), P(dp, "model"),
                   P(dp)),
         out_specs=(P(dp), P(dp, "model"), P(dp, "model")),
-        check=False)(q, k_new, v_new, kcache, vcache, posv)
+        check_vma=False)(q, k_new, v_new, kcache, vcache, posv)
 
 
 def _apply_mla(p, x, cfg, plan, mode, positions, cache, pos, pages=None):
@@ -410,12 +410,12 @@ def _mla_decode_update(plan, p, q_nope, q_rope, c_new, kr_new, c_cache,
                          rank0=r * Sl)
             return lat, cb, krb
 
-        lat, cc, krc = shard_map(
+        lat, cc, krc = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dp), P(dp), P(dp), P(dp), P(dp, "model"),
                       P(dp, "model"), P(dp)),
             out_specs=(P(dp), P(dp, "model"), P(dp, "model")),
-            check=False)(q_eff, qr, c_new, kr_new, c_cache, kr_cache, posv)
+            check_vma=False)(q_eff, qr, c_new, kr_new, c_cache, kr_cache, posv)
 
     w_uv = p["w_uv"].reshape(m.kv_lora, H, m.d_v)
     o = jnp.einsum("bhq,qhv->bhv", lat.astype(q_nope.dtype), w_uv)

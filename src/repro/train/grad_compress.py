@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import shard_map
-
 
 def quantize_int8(x):
     scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12) / 127.0
@@ -74,9 +72,9 @@ def make_compressed_allreduce(mesh, axis: str = "data"):
             return (jax.tree_util.tree_unflatten(treedef, [o[0] for o in outs]),
                     jax.tree_util.tree_unflatten(treedef, [o[1] for o in outs]))
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
-            check=False)(grads, residuals)
+            check_vma=False)(grads, residuals)
 
     return sync

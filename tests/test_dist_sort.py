@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models import common
 from repro.utils import segops
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -58,9 +57,10 @@ def _mesh_sort(keys_np, pays_np, striped=False, with_stats=False, **kw):
         return (*ko, *po)
 
     n_out = nk + len(pays) + (1 if with_stats else 0)
-    f = jax.jit(common.shard_map(body, mesh=mesh,
-                                 in_specs=tuple(P() for _ in keys + pays),
-                                 out_specs=tuple(P() for _ in range(n_out))))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=tuple(P() for _ in keys + pays),
+                              out_specs=tuple(P() for _ in range(n_out)),
+                              check_vma=False))
     out = [np.asarray(o) for o in f(*keys, *pays)]
     if with_stats:
         return out[:-1], bool(out[-1].reshape(-1)[0])
@@ -181,7 +181,6 @@ _MULTIDEV = textwrap.dedent("""
     import numpy as np
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.models import common
     from repro.utils import segops
 
     assert len(jax.devices()) == 8
@@ -198,9 +197,10 @@ _MULTIDEV = textwrap.dedent("""
             out = dist_sort.sample_sort_stripes(ctx, ks, ps,
                                                 with_stats=True, **kw)
             return (*[ctx.gather(c) for c in out[0] + out[1]], out[2])
-        f = jax.jit(common.shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=tuple(P() for _ in keys + pays),
-            out_specs=tuple(P() for _ in range(nk + len(pays) + 1))))
+            out_specs=tuple(P() for _ in range(nk + len(pays) + 1)),
+            check_vma=False))
         out = [np.asarray(o) for o in f(*keys, *pays)]
         return out[:-1], bool(out[-1].reshape(-1)[0])
 
@@ -263,8 +263,8 @@ _MULTIDEV = textwrap.dedent("""
                 ctx.gather(ctx.edge_next(vs, -9)),
                 ctx.gather(ctx.cumsum(vs)),
                 ctx.unstripe(vs))
-    f = jax.jit(common.shard_map(bh, mesh=mesh, in_specs=(P(),),
-                                 out_specs=(P(), P(), P(), P())))
+    f = jax.jit(jax.shard_map(bh, mesh=mesh, in_specs=(P(),),
+                              out_specs=(P(), P(), P(), P()), check_vma=False))
     prev, nxt, cs, us = map(np.asarray, f(x))
     xn = np.asarray(x)
     assert np.array_equal(prev, np.concatenate([[-7], xn[:-1]]))

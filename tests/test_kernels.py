@@ -13,10 +13,13 @@ from repro.kernels.pins_count.kernel import pins_count_pallas
 from repro.kernels.pins_count.ref import pins_count_ref
 
 
+# k > 512 walks several partition chunks; k = 768 also pads the last one,
+# so padding ids in [k, 1024) must be remapped past it (ids >= k are padding)
 @pytest.mark.parametrize("e,d,k", [(8, 128, 8), (16, 256, 16), (32, 128, 4),
-                                   (8, 384, 64)])
+                                   (8, 384, 64), (8, 128, 1024),
+                                   (8, 128, 768)])
 def test_pins_count_sweep(e, d, k, rng):
-    parts = rng.integers(0, k + 1, size=(e, d)).astype(np.int32)
+    parts = rng.integers(0, k + 1 + k // 4, size=(e, d)).astype(np.int32)
     dst = rng.integers(0, 2, size=(e, d)).astype(np.int32)
     p1, pi1 = pins_count_pallas(jnp.asarray(parts), jnp.asarray(dst), k,
                                 te=8, dc=128)
@@ -42,11 +45,17 @@ def test_pair_scores_sweep(n, u, l, wdtype, rng):
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
 
+# k = 2176 walks two 2048-lane chunks, the second mostly padding; h = 72
+# stages its slots in two DMA rounds (64 + 8)
 @pytest.mark.parametrize("n,h,e,k", [(8, 8, 16, 8), (16, 16, 64, 16),
-                                     (8, 4, 32, 128)])
+                                     (8, 4, 32, 128), (8, 8, 16, 2176),
+                                     (8, 72, 96, 16)])
 def test_gains_sweep(n, h, e, k, rng):
     inc = rng.integers(0, e, size=(n * h,)).astype(np.int32)
     w = rng.random((n, h)).astype(np.float32)
+    # dead slots (w == 0, as a node below the degree bound pads its row)
+    # are skipped by the kernel and must leave the sums unchanged
+    w[rng.random((n, h)) < 0.3] = 0.0
     pnz = (rng.random((e, k)) > 0.5).astype(np.float32)
     c1 = gains_pallas(jnp.asarray(inc), jnp.asarray(w), jnp.asarray(pnz), h=h)
     c2 = gains_ref(jnp.asarray(inc), jnp.asarray(w), jnp.asarray(pnz), h)

@@ -19,19 +19,19 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 
 
 def test_interpret_policy_env_override(monkeypatch):
-    """`pallas_interpret` compiles on accelerators, interprets on host, and
-    REPRO_PALLAS_INTERPRET=1 forces interpret everywhere; =0 stays a no-op
-    on CPU (no compiled Pallas path exists there)."""
+    """`pallas_interpret` follows the backend alone: interpret on CPU,
+    compiled on TPU, and an error on any other backend."""
     import jax
     from repro.kernels import pallas_interpret
 
-    on_host = jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert pallas_interpret() is on_host
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert jax.default_backend() == "cpu"
     assert pallas_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert pallas_interpret() is on_host  # CPU degrades to interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    for other in ("gpu", "cuda", "rocm"):
+        monkeypatch.setattr(jax, "default_backend", lambda o=other: o)
+        with pytest.raises(RuntimeError, match="neither"):
+            pallas_interpret()
 
 
 def _pair_setup(k_pins):
@@ -128,7 +128,6 @@ _SHARDED = textwrap.dedent("""
     from repro.dist.sharding import Plan
     from repro.kernels.gains import ops as g_ops
     from repro.kernels.pair_scores import ops as ps_ops
-    from repro.models import common
     from repro.utils import segops
 
     assert len(jax.devices()) == 8
@@ -153,9 +152,9 @@ _SHARDED = textwrap.dedent("""
         fits = ps_ops.fits_kernel(d_, nb, prs, caps, ctx)
         eta, inter = ps_ops.score_slots_kernel(d_, nb, prs, caps, ctx)
         return fits, eta, inter
-    ps_fn = jax.jit(common.shard_map(
+    ps_fn = jax.jit(jax.shard_map(
         ps_body, mesh=mesh, in_specs=(graph_pspecs(False),),
-        out_specs=(P(), P(), P())))
+        out_specs=(P(), P(), P()), check_vma=False))
     fits8, eta8, inter8 = ps_fn(d)
     assert bool(fits8)
     assert np.array_equal(np.asarray(eta8), np.asarray(eta0))
@@ -176,9 +175,9 @@ _SHARDED = textwrap.dedent("""
     def g_body(d_, parts_):
         pins, _ = R.pins_matrix(d_, parts_, caps, kcap, ctx)
         return g_ops.conn_weights(d_, parts_, pins, caps, kcap, ctx)
-    g_fn = jax.jit(common.shard_map(
+    g_fn = jax.jit(jax.shard_map(
         g_body, mesh=mesh, in_specs=(graph_pspecs(False), P()),
-        out_specs=P()))
+        out_specs=P(), check_vma=False))
     conn8 = g_fn(d, parts)
     assert np.array_equal(np.asarray(conn8), np.asarray(conn0))
     # segment-path oracle (the _conn_segments computation, single device)

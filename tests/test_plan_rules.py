@@ -11,10 +11,10 @@ from repro.models.common import Spec, _resolve_pspec
 
 
 def _mesh(data=2, model=4, pod=None):
-    shape = (("data", data), ("model", model))
+    sizes, names = (data, model), ("data", "model")
     if pod is not None:
-        shape = (("pod", pod),) + shape
-    return AbstractMesh(shape)
+        sizes, names = (pod,) + sizes, ("pod",) + names
+    return AbstractMesh(sizes, names)
 
 
 def test_fsdp_on_shards_embed_over_data():
