@@ -1,0 +1,10 @@
+"""CPU tests of the benchmark's own code: ``PYTHONPATH=src python -m
+pytest bench/tests -q`` from the repository root (JAX on the CPU, Pallas
+kernels interpreted)."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
