@@ -236,15 +236,21 @@ def coarsen_step_impl(d: DeviceHypergraph, caps: Caps, params: CoarsenParams,
     Single source of truth for the jitted single-device ``coarsen_step``
     and ``dist.partition.coarsen_level``'s shard_map'd body (``ctx`` stripes
     the pairs/slot pipelines; the isolated-node pairing sort stays
-    replicated — its inputs are node-sized and already replicated)."""
+    replicated — its inputs are node-sized and already replicated). The
+    phases run under the ``jax.named_scope``s pairs, neighbors, propose and
+    matching, so their device ops carry stable names."""
     from repro.core.hypergraph import build_neighbors, build_pairs
 
-    pidx, pidx_ok = ctx.lanes(caps.pairs)
-    pairs = build_pairs(d, caps, idx=pidx, idx_ok=pidx_ok, ctx=ctx)
-    nbrs = build_neighbors(pairs, d, caps, ctx)
-    props = propose(d, nbrs, pairs, caps, params, ctx)
-    match = run_matching_rounds(props, d, caps, params, ctx)
-    match = pair_isolated(match, props, d, caps, params)
+    with jax.named_scope("pairs"):
+        pidx, pidx_ok = ctx.lanes(caps.pairs)
+        pairs = build_pairs(d, caps, idx=pidx, idx_ok=pidx_ok, ctx=ctx)
+    with jax.named_scope("neighbors"):
+        nbrs = build_neighbors(pairs, d, caps, ctx)
+    with jax.named_scope("propose"):
+        props = propose(d, nbrs, pairs, caps, params, ctx)
+    with jax.named_scope("matching"):
+        match = run_matching_rounds(props, d, caps, params, ctx)
+        match = pair_isolated(match, props, d, caps, params)
     n_pairs = jnp.sum((match >= 0) & (jnp.arange(caps.n) < d.n_nodes)) // 2
     props = dataclasses.replace(props, n_pairs_live=pairs.n_pairs,
                                 n_nbr_entries=nbrs.n_entries)

@@ -612,8 +612,10 @@ def shrink_device(d: DeviceHypergraph, caps: Caps) -> tuple[DeviceHypergraph, Ca
     blocking O(E) ``edge_off`` readback.
     """
     import math as _math
-    n_live, p_live, pair_live = (int(v) for v in jax.device_get(
-        [d.n_nodes, d.n_pins, device_pair_count(d.edge_off)]))
+    from repro.obs import trace as otrace
+    n_live, p_live, pair_live = (int(v) for v in otrace.device_sync(
+        [d.n_nodes, d.n_pins, device_pair_count(d.edge_off)],
+        "shrink_scalars"))
     new_n = 1 << max(0, _math.ceil(_math.log2(max(n_live, 1))))
     new_p = 1 << max(0, _math.ceil(_math.log2(max(p_live, 1))))
     if new_n >= caps.n and new_p >= caps.p:

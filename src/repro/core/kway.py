@@ -92,7 +92,7 @@ def partition_kway(hg: HostHypergraph, k: int, eps: float = 0.03,
     omega = max(int((1 + eps) * hg.n_nodes / k), math.ceil(hg.n_nodes / k))
     with otrace.span("partition_kway", nodes=hg.n_nodes, edges=hg.n_edges,
                      k=k, omega=omega) as sp_total:
-        with otrace.span("setup"):
+        with otrace.span("setup") as sp_setup:
             caps = Caps.for_host(hg)
             # exact int64 level-0 audit (see partitioner.partition): with
             # this passed the per-level int32 device counts cannot wrap
@@ -105,6 +105,7 @@ def partition_kway(hg: HostHypergraph, k: int, eps: float = 0.03,
                 d = sharded_from_host(hg, caps, plan)
             else:
                 d = device_from_host(hg, caps)
+            sp_setup.sync(d)  # the upload drains here, not in coarsening
         cparams = CoarsenParams(omega=omega, delta=BIG_DELTA,
                                 n_cands=n_cands, use_kernels=use_kernels)
         if coarse_target is None:
@@ -121,16 +122,20 @@ def partition_kway(hg: HostHypergraph, k: int, eps: float = 0.03,
                 run_coarsen_loop(d, caps, coarse_target, max_levels,
                                  _coarsen, _contract,
                                  log if collect_log else None)
-        check_expansion_caps(caps, device_pair_count(d.edge_off))
+        check_expansion_caps(caps, otrace.device_sync(
+            device_pair_count(d.edge_off), "pair_count"))
 
         # ---- initial k-way on the coarsest graph (host, tiny) ------------
         with otrace.span("initial_kway"):
             if shard_graph:
                 from repro.dist.graph import host_from_sharded
-                coarse_host = host_from_sharded(d)
+                coarse_host = otrace.device_sync(d, "coarse_graph",
+                                                 host_from_sharded)
             else:
-                coarse_host = host_from_device(d)
-            coarse_sizes = np.asarray(d.node_size)[: coarse_host.n_nodes]
+                coarse_host = otrace.device_sync(d, "coarse_graph",
+                                                 host_from_device)
+            coarse_sizes = otrace.device_sync(
+                d.node_size, "coarse_sizes")[: coarse_host.n_nodes]
             init = greedy_initial_kway(coarse_host, coarse_sizes, k, omega)
             kcap = _next_pow2(k)
             parts = jnp.zeros((caps.n,), jnp.int32)
@@ -152,11 +157,13 @@ def partition_kway(hg: HostHypergraph, k: int, eps: float = 0.03,
             run_refine_loop(d, parts, caps, levels, gammas, _refine, kcap,
                             omega, BIG_DELTA, collect_stats, None)
         refine_meta[len(levels)]["structure"] = dict(
-            nodes=coarse_host.n_nodes, edges=int(d.n_edges),
-            pins=int(d.n_pins))
+            nodes=coarse_host.n_nodes,
+            edges=int(otrace.device_sync(d.n_edges, "coarsest_edges")),
+            pins=int(otrace.device_sync(d.n_pins, "coarsest_pins")))
 
         with otrace.span("audit"):
-            parts_np = np.asarray(parts)[: hg.n_nodes].astype(np.int64)
+            parts_np = otrace.device_sync(parts, "parts")[: hg.n_nodes] \
+                .astype(np.int64)
             aud = metrics.audit(hg, parts_np, omega=omega, delta=BIG_DELTA)
             aud["balance_eps"] = metrics.balance_epsilon(parts_np, k)
     return PartitionResult(

@@ -29,7 +29,7 @@ from repro.core.hypergraph import (Caps, GraphDelta, HostHypergraph,
                                    check_expansion_caps, check_fits_caps,
                                    device_from_host, device_pair_count,
                                    host_pair_count)
-from repro.core.refine import RefineParams, refine_level
+from repro.core.refine import LEVEL_COUNTERS, RefineParams, refine_level
 from repro.obs import trace as otrace
 from repro.obs import vcycle as ovcycle
 
@@ -109,9 +109,10 @@ def make_refine_fn(k, kcap: int, rparams: RefineParams, rlog,
     `kway.partition_kway`: plain `refine_level` without a plan, the
     mesh-raced/sharded `dist.partition.refine_level` with one (seed offset
     by level so replica tie-break permutations decorrelate across levels).
-    Returns `fn(d, parts, caps, level) -> (parts, (kernel_hits,
-    pins_hits))` — the trailing device scalars count the level's
-    repetitions whose gains / pins dispatch took the Pallas branch."""
+    Returns `fn(d, parts, caps, level) -> (parts, counters)` — the level's
+    int32[6] device vector in `refine.LEVEL_COUNTERS` order (Pallas-branch
+    repetitions of the gains / pins dispatch, moves proposed and applied,
+    repetitions that applied no move, theta)."""
     if plan is None:
         def _refine(d_, parts_, caps_, lvl_):
             return refine_level(d_, parts_, k, caps_, kcap, rparams, rlog)
@@ -144,12 +145,14 @@ def run_coarsen_loop(d, caps: Caps, target: int, max_levels: int,
     from repro.core.hypergraph import shrink_device
 
     levels, gammas, coarsen_hits, coarsen_meta = [], [], [], []
-    while int(d.n_nodes) > target and len(gammas) < max_levels:
+    while (int(otrace.device_sync(d.n_nodes, "coarsen_nodes")) > target
+           and len(gammas) < max_levels):
         with otrace.span("coarsen_level", level=len(gammas)):
             match, n_pairs, ovf = _coarsen(d, caps)
             (pairs_live, nbr_entries, kern_hit, n_pairs_h, nodes_h, edges_h,
-             pins_h) = (int(v) for v in jax.device_get(
-                 [*ovf, n_pairs, d.n_nodes, d.n_edges, d.n_pins]))
+             pins_h) = (int(v) for v in otrace.device_sync(
+                 [*ovf, n_pairs, d.n_nodes, d.n_edges, d.n_pins],
+                 "level_scalars"))
             check_expansion_caps(caps, pairs_live, nbr_entries)
             if n_pairs_h == 0:
                 break
@@ -170,7 +173,7 @@ def run_coarsen_loop(d, caps: Caps, target: int, max_levels: int,
             d = d2
             if shrink:
                 d, caps = shrink_device(d, caps)
-    jax.block_until_ready((d, gammas))
+    otrace.device_sync((d, gammas), "coarsen_tail", jax.block_until_ready)
     return d, caps, levels, gammas, coarsen_hits, coarsen_meta
 
 
@@ -187,17 +190,22 @@ def run_refine_loop(d, parts, caps: Caps, levels, gammas, _refine,
     before the span closes.
 
     Returns ``(parts, refine_span, refine_meta, refine_hits, pins_hits)``
-    — ``refine_meta`` one dict per refined level (``kernel_refine`` /
-    ``quality`` keys, for `obs.vcycle.assemble`), the hits lists the
-    per-level Pallas-branch repetition counts (gains / pins dispatch) for
-    ``PartitionResult.kernel_path``. With ``levels=[]`` (warm start) this
-    is a single-level refine of ``d`` — no projection, no coarsening."""
+    — ``refine_meta`` one dict per refined level (its
+    `refine.LEVEL_COUNTERS` and ``quality``, for `obs.vcycle.assemble`), the
+    hits lists the per-level Pallas-branch repetition counts (gains / pins
+    dispatch) for ``PartitionResult.kernel_path``. Each ``refine_level``
+    span is annotated with its ``moves_proposed``, ``moves_applied``,
+    ``noop_reps`` and ``reps`` once the readback has them. With
+    ``levels=[]`` (warm start) this is a single-level refine of ``d`` — no
+    projection, no coarsening."""
     quality_dev: dict = {}
-    hits_dev: dict = {}
+    counters_dev: dict = {}
+    level_spans: dict = {}
     with otrace.span("refine") as sp_refine:
-        with otrace.span("refine_level", level=len(levels)):
-            parts, hits_dev[len(levels)] = _refine(d, parts, caps,
-                                                   len(levels))
+        with otrace.span("refine_level", level=len(levels)) as sp:
+            parts, counters_dev[len(levels)] = _refine(d, parts, caps,
+                                                       len(levels))
+        level_spans[len(levels)] = sp
         if collect_stats:
             quality_dev[len(levels)] = ovcycle.quality_scalars(
                 d, parts, caps, kcap, omega, delta)
@@ -205,28 +213,32 @@ def run_refine_loop(d, parts, caps: Caps, levels, gammas, _refine,
             g = gammas[lvl]
             d_lvl, caps_lvl = levels[lvl]
             coarse_cap = parts.shape[0]
-            with otrace.span("refine_level", level=lvl):
+            with otrace.span("refine_level", level=lvl) as sp:
                 parts = jnp.where(
                     jnp.arange(caps_lvl.n) < d_lvl.n_nodes,
                     parts[jnp.clip(g[: caps_lvl.n], 0, coarse_cap - 1)], 0)
-                parts, hits_dev[lvl] = _refine(d_lvl, parts, caps_lvl, lvl)
+                parts, counters_dev[lvl] = _refine(d_lvl, parts, caps_lvl,
+                                                   lvl)
+            level_spans[lvl] = sp
             if collect_stats:
                 quality_dev[lvl] = ovcycle.quality_scalars(
                     d_lvl, parts, caps_lvl, kcap, omega, delta)
             if log is not None:
                 log.append(dict(kind="refine", level=lvl))
         # block before the span closes: the refine tail would otherwise
-        # drain inside the caller's np.asarray(parts), after the timer
-        # stopped
-        jax.block_until_ready(parts)
-    # ONE batched readback for the kernel hits + quality scalars
-    hits_h, quality_h = jax.device_get(
-        ([hits_dev[i] for i in range(len(levels) + 1)], quality_dev))
-    refine_hits = [int(kt) for kt, _ in hits_h]
-    pins_hits = [int(pt) for _, pt in hits_h]
-    refine_meta = {
-        lvl: dict(kernel_refine=refine_hits[lvl], quality=quality_h.get(lvl))
-        for lvl in range(len(levels) + 1)}
+        # drain inside the caller's parts readback, after the timer stopped
+        otrace.device_sync(parts, "refine_tail", jax.block_until_ready)
+    # ONE batched readback for the level counters + quality scalars
+    counters_h, quality_h = otrace.device_sync(
+        ([counters_dev[i] for i in range(len(levels) + 1)], quality_dev),
+        "refine_readback")
+    refine_meta = {}
+    for lvl, c in enumerate(counters_h):
+        cnt = dict(zip(LEVEL_COUNTERS, (int(v) for v in c)))
+        level_spans[lvl].annotate(**{k: cnt[k] for k in LEVEL_COUNTERS[2:]})
+        refine_meta[lvl] = dict(cnt, quality=quality_h.get(lvl))
+    refine_hits = [m["kernel_hits"] for m in refine_meta.values()]
+    pins_hits = [m["pins_hits"] for m in refine_meta.values()]
     return parts, sp_refine, refine_meta, refine_hits, pins_hits
 
 
@@ -412,7 +424,7 @@ def partition(hg: HostHypergraph, omega: int, delta: int,
     """
     with otrace.span("partition", nodes=hg.n_nodes, edges=hg.n_edges,
                      pins=hg.n_pins, omega=omega, delta=delta) as sp_total:
-        with otrace.span("setup"):
+        with otrace.span("setup") as sp_setup:
             caps = Caps.for_host(hg, pair_cap=pair_cap, nbr_cap=nbr_cap)
             # exact int64 level-0 audit before any device work: with this
             # passed, pair monotonicity under coarsening bounds every
@@ -438,6 +450,7 @@ def partition(hg: HostHypergraph, omega: int, delta: int,
                 d = sharded_from_host(hg, caps, plan)
             else:
                 d = device_from_host(hg, caps)
+            sp_setup.sync(d)  # the upload drains here, not in coarsening
         cparams = CoarsenParams(omega=omega, delta=delta, n_cands=n_cands,
                                 use_kernels=use_kernels, matching=matching)
 
@@ -457,10 +470,11 @@ def partition(hg: HostHypergraph, omega: int, delta: int,
         # coarsening, so audit its pair expansion (refinement's in-sequence
         # gains expand the same pairs) — every earlier level was audited in
         # the loop
-        check_expansion_caps(caps, device_pair_count(d.edge_off))
+        check_expansion_caps(caps, otrace.device_sync(
+            device_pair_count(d.edge_off), "pair_count"))
 
         # initial partitioning == coarsest clusters (Sec. III)
-        k = int(d.n_nodes)
+        k = int(otrace.device_sync(d.n_nodes, "coarsest_nodes"))
         if kcap_hint is None:
             kcap = _next_pow2(k)
         else:
@@ -481,7 +495,10 @@ def partition(hg: HostHypergraph, omega: int, delta: int,
         _refine = make_refine_fn(k, kcap, rparams, rlog, plan, race,
                                  race_seed)
 
-        structure = dict(nodes=k, edges=int(d.n_edges), pins=int(d.n_pins))
+        structure = dict(
+            nodes=k,
+            edges=int(otrace.device_sync(d.n_edges, "coarsest_edges")),
+            pins=int(otrace.device_sync(d.n_pins, "coarsest_pins")))
 
         # refine the coarsest level too, then every uncoarsened level
         # (shared with kway/refine_from; one batched readback at the end)
@@ -492,7 +509,8 @@ def partition(hg: HostHypergraph, omega: int, delta: int,
         refine_meta[len(levels)]["structure"] = structure
 
         with otrace.span("audit"):
-            parts_np = np.asarray(parts)[: hg.n_nodes].astype(np.int64)
+            parts_np = otrace.device_sync(parts, "parts")[: hg.n_nodes] \
+                .astype(np.int64)
             # compact partition ids (refinement may empty some partitions)
             uniq, parts_np = np.unique(parts_np, return_inverse=True)
             aud = metrics.audit(hg, parts_np, omega=omega, delta=delta)
@@ -544,7 +562,7 @@ def refine_from(hg: HostHypergraph, parts, omega: int, delta: int,
     with otrace.span("partition", nodes=hg.n_nodes, edges=hg.n_edges,
                      pins=hg.n_pins, omega=omega, delta=delta,
                      mode=mode) as sp_total:
-        with otrace.span("setup"):
+        with otrace.span("setup") as sp_setup:
             if (device_graph is None) != (caps is None):
                 raise ValueError(
                     "device_graph and caps must be passed together")
@@ -589,6 +607,7 @@ def refine_from(hg: HostHypergraph, parts, omega: int, delta: int,
                 kcap = kcap_hint
             parts_dev = jnp.zeros((caps.n,), jnp.int32).at[: hg.n_nodes].set(
                 jnp.asarray(parts_in, jnp.int32))
+            sp_setup.sync((d, parts_dev))
 
         rparams = refine_params or RefineParams(
             omega=omega, delta=delta, theta=theta, use_kernels=use_kernels,
@@ -605,7 +624,8 @@ def refine_from(hg: HostHypergraph, parts, omega: int, delta: int,
             nodes=hg.n_nodes, edges=hg.n_edges, pins=hg.n_pins)
 
         with otrace.span("audit"):
-            parts_np = np.asarray(parts_dev)[: hg.n_nodes].astype(np.int64)
+            parts_np = otrace.device_sync(parts_dev, "parts")[: hg.n_nodes] \
+                .astype(np.int64)
             if n_parts is None:
                 uniq, parts_np = np.unique(parts_np, return_inverse=True)
                 n_out = len(uniq)

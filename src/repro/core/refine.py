@@ -505,6 +505,12 @@ def events_validity(d: DeviceHypergraph, parts: jax.Array,
 # ---------------------------------------------------------------------------
 # 6. one refinement repetition + level driver
 # ---------------------------------------------------------------------------
+# Per-repetition counts (int32[4], the step's third output) and per-level
+# counters (int32[6], `level_counters`), by position.
+REP_COUNTS = ("kernel_hits", "pins_hits", "moves_proposed", "moves_applied")
+LEVEL_COUNTERS = REP_COUNTS + ("noop_reps", "reps")
+
+
 def refine_step_impl(d: DeviceHypergraph, parts: jax.Array,
                      n_parts: jax.Array, caps: Caps, kcap: int,
                      params: RefineParams, enforce_size: jax.Array,
@@ -514,32 +520,46 @@ def refine_step_impl(d: DeviceHypergraph, parts: jax.Array,
     events). Single source of truth for both the jitted single-device
     ``refine_step`` and ``dist.partition``'s shard_map'd racing step
     (``ctx`` shards the pins/pairs pipelines, ``tie_rank`` diversifies
-    replicas)."""
-    if params.use_kernels:
-        from repro.kernels.pins_count import ops as pc_ops
-        # replicated mesh-independent predicate (branch parity, see
-        # repro.kernels): the pins kernel runs stripe-locally per shard
-        fits = pc_ops.fits_kernel(d, caps)
-        pins, pins_in = jax.lax.cond(
-            fits,
-            lambda: pc_ops.pins_matrix_kernel(d, parts, caps, kcap, ctx),
-            lambda: pins_matrix(d, parts, caps, kcap, ctx))
-        pins_taken = fits.astype(jnp.int32)
-    else:
-        pins, pins_in = pins_matrix(d, parts, caps, kcap, ctx)
-        pins_taken = jnp.int32(0)
-    move_to, gain_iso, _, kernel_taken = propose_moves(
-        d, parts, pins, caps, kcap, params, enforce_size, n_parts, ctx)
-    seq, _ = build_sequence(d, parts, move_to, gain_iso, caps, kcap, params,
-                            tie_rank=tie_rank, ctx=ctx)
-    gain_seq = inseq_gains(d, parts, pins, move_to, gain_iso, seq, caps,
-                           kcap, ctx)
-    apply_mask, applied_gain = events_validity(
-        d, parts, pins_in, move_to, seq, gain_seq, caps, kcap, params, ctx)
+    replicas).
+
+    Returns ``(parts_new, applied_gain, counts)``: ``counts`` is int32[4]
+    in `REP_COUNTS` order — whether the gains and the pins dispatch took
+    the Pallas branch (0/1 each), the moves proposed (nodes with a
+    destination) and the moves applied. Each phase runs under a
+    ``jax.named_scope`` of its own, so its device ops carry a stable name."""
+    with jax.named_scope("pins"):
+        if params.use_kernels:
+            from repro.kernels.pins_count import ops as pc_ops
+            # replicated mesh-independent predicate (branch parity, see
+            # repro.kernels): the pins kernel runs stripe-locally per shard
+            fits = pc_ops.fits_kernel(d, caps)
+            pins, pins_in = jax.lax.cond(
+                fits,
+                lambda: pc_ops.pins_matrix_kernel(d, parts, caps, kcap, ctx),
+                lambda: pins_matrix(d, parts, caps, kcap, ctx))
+            pins_taken = fits.astype(jnp.int32)
+        else:
+            pins, pins_in = pins_matrix(d, parts, caps, kcap, ctx)
+            pins_taken = jnp.int32(0)
+    with jax.named_scope("propose"):
+        move_to, gain_iso, _, kernel_taken = propose_moves(
+            d, parts, pins, caps, kcap, params, enforce_size, n_parts, ctx)
+    with jax.named_scope("chains"):
+        seq, _ = build_sequence(d, parts, move_to, gain_iso, caps, kcap,
+                                params, tie_rank=tie_rank, ctx=ctx)
+    with jax.named_scope("inseq_gains"):
+        gain_seq = inseq_gains(d, parts, pins, move_to, gain_iso, seq, caps,
+                               kcap, ctx)
+    with jax.named_scope("events"):
+        apply_mask, applied_gain = events_validity(
+            d, parts, pins_in, move_to, seq, gain_seq, caps, kcap, params,
+            ctx)
     parts_new = jnp.where(apply_mask, jnp.where(move_to >= 0, move_to, parts),
                           parts)
-    return (parts_new, applied_gain,
-            jnp.sum(apply_mask.astype(jnp.int32)), kernel_taken, pins_taken)
+    counts = jnp.stack([kernel_taken, pins_taken,
+                        jnp.sum((move_to >= 0).astype(jnp.int32)),
+                        jnp.sum(apply_mask.astype(jnp.int32))])
+    return parts_new, applied_gain, counts
 
 
 @partial(jax.jit, static_argnames=("caps", "kcap", "params"))
@@ -552,23 +572,46 @@ def refine_step(d: DeviceHypergraph, parts: jax.Array, n_parts: jax.Array,
                             jnp.asarray(enforce_size, bool))
 
 
+@jax.jit
+def level_counters(rep_counts: list) -> jax.Array:
+    """One level's counters, int32[6] in `LEVEL_COUNTERS` order, from its
+    repetitions' `REP_COUNTS` vectors: their sums, the repetitions that
+    applied no move, and the repetition count. One dispatch per level."""
+    c = jnp.stack(rep_counts)
+    applied = c[:, REP_COUNTS.index("moves_applied")]
+    return jnp.concatenate([jnp.sum(c, axis=0),
+                            jnp.stack([jnp.sum((applied == 0)
+                                               .astype(jnp.int32)),
+                                       jnp.int32(c.shape[0])])])
+
+
+def log_rep(log: list, rep: int, gain, counts, **extra) -> None:
+    """Append one repetition's entry to a ``collect_log`` log: one
+    ``device_sync`` read of its gain and counts."""
+    from repro.obs import trace as otrace
+    g, c = otrace.device_sync((gain, counts), "refine_log")
+    log.append(dict(rep=rep, gain=float(g),
+                    applied=int(c[REP_COUNTS.index("moves_applied")]),
+                    proposed=int(c[REP_COUNTS.index("moves_proposed")]),
+                    kernel=int(c[REP_COUNTS.index("kernel_hits")]), **extra))
+
+
 def refine_level(d: DeviceHypergraph, parts: jax.Array, n_parts,
                  caps: Caps, kcap: int, params: RefineParams,
                  log: list | None = None):
     """Theta repetitions; first half may propose size-violating moves.
-    Returns (parts, (kernel_hits, pins_hits)) — device-scalar counts of
-    repetitions whose gains / pins dispatch took the Pallas branch
-    (each 0..theta)."""
+    Returns ``(parts, counters)`` — ``counters`` the level's int32[6]
+    device vector in `LEVEL_COUNTERS` order (`level_counters`): the
+    repetitions whose gains / pins dispatch took the Pallas branch, the
+    moves proposed and applied, the repetitions that applied none, and
+    theta. No host sync unless ``log`` is given."""
     n_parts = jnp.asarray(n_parts, jnp.int32)
-    hits = jnp.int32(0)
-    phits = jnp.int32(0)
+    reps = []
     for rep in range(params.theta):
         enforce = rep >= params.theta // 2
-        parts, g, nmv, kt, pt = refine_step(d, parts, n_parts, caps, kcap,
-                                            params, enforce)
-        hits = hits + kt
-        phits = phits + pt
+        parts, g, counts = refine_step(d, parts, n_parts, caps, kcap,
+                                       params, enforce)
+        reps.append(counts)
         if log is not None:
-            log.append(dict(rep=rep, gain=float(g), applied=int(nmv),
-                            kernel=int(kt)))
-    return parts, (hits, phits)
+            log_rep(log, rep, g, counts)
+    return parts, level_counters(reps)

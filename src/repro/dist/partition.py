@@ -121,7 +121,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.coarsen import CoarsenParams, coarsen_step_impl
 from repro.core.contract import contract_impl
 from repro.core.hypergraph import Caps
-from repro.core.refine import RefineParams, refine_step_impl
+from repro.core.refine import (RefineParams, level_counters, log_rep,
+                               refine_step_impl)
 from repro.dist.graph import ShardedHypergraph, graph_pspecs
 from repro.dist.sharding import Plan
 from repro.utils import segops
@@ -181,24 +182,23 @@ def _build_step(mesh, data_axis: str, model_axis: str | None, nshards: int,
             tie_rank = jnp.where(r == 0, ids, perm)
         else:
             tie_rank = ids
-        parts_new, gain, nmv, kt, pt = refine_step_impl(
+        parts_new, gain, counts = refine_step_impl(
             d, parts, n_parts, caps, kcap, params, enforce, ctx, tie_rank)
         if data_axis is None:   # shard-only mesh: nothing to race
-            return parts_new, gain, nmv, kt, pt
+            return parts_new, gain, counts
         # race resolution: scalar gains all-gathered, winner's partition
-        # vector broadcast by psum of the masked vector (no parts gather)
+        # vector and counts broadcast by psum of the masked values (no
+        # parts gather)
         gains = jax.lax.all_gather(gain, data_axis)        # [n_replicas]
         best = jnp.argmax(gains).astype(jnp.int32)         # tie -> replica 0
         win = jax.lax.axis_index(data_axis) == best
         parts_out = jax.lax.psum(jnp.where(win, parts_new, 0), data_axis)
-        nmv_out = jax.lax.psum(jnp.where(win, nmv, 0), data_axis)
-        kt_out = jax.lax.psum(jnp.where(win, kt, 0), data_axis)
-        pt_out = jax.lax.psum(jnp.where(win, pt, 0), data_axis)
-        return parts_out, gains[best], nmv_out, kt_out, pt_out
+        counts_out = jax.lax.psum(jnp.where(win, counts, 0), data_axis)
+        return parts_out, gains[best], counts_out
 
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(graph_pspecs(striped), P(), P(), P(), P()),
-                       out_specs=(P(), P(), P(), P(), P()), check_vma=False)
+                       out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -216,11 +216,11 @@ def refine_level(d, parts, n_parts, caps: Caps, kcap: int,
     With ``use_kernels=True`` the gains/pins dispatches of
     ``core.refine`` stay live on the mesh: the `gains` kernel runs
     stripe-locally per shard (see `repro.kernels`), bit-identical to the
-    single-device kernel path. Returns ``(parts, kernel_hits)`` — the
-    device-scalar count of repetitions whose gains dispatch took the
-    Pallas branch (0..theta; mesh-independent by the branch-parity
-    invariant). The same holds for the stripe-local pins-count dispatch;
-    ``refine_level`` returns ``(parts, (kernel_hits, pins_hits))``."""
+    single-device kernel path. Returns ``(parts, counters)`` as
+    `core.refine.refine_level` does: the winning replicas' counts per
+    repetition, reduced once per level (`refine.level_counters`; the
+    kernel-branch counts are mesh-independent by the branch-parity
+    invariant)."""
     d, striped = _graph_arg(d)
     data_axis, model_axis, nshards = plan_axes(plan)
     step = _build_step(plan.mesh, data_axis, model_axis, nshards,
@@ -230,18 +230,15 @@ def refine_level(d, parts, n_parts, caps: Caps, kcap: int,
     # uncommitted first input would compile the step a second time
     parts = jax.device_put(parts, NamedSharding(plan.mesh, P()))
     key = jax.random.PRNGKey(seed)
-    hits = jnp.int32(0)
-    phits = jnp.int32(0)
+    reps = []
     for rep in range(params.theta):
         enforce = jnp.asarray(rep >= params.theta // 2)
-        parts, g, nmv, kt, pt = step(d, parts, n_parts,
-                                     jax.random.fold_in(key, rep), enforce)
-        hits = hits + kt
-        phits = phits + pt
+        parts, g, counts = step(d, parts, n_parts,
+                                jax.random.fold_in(key, rep), enforce)
+        reps.append(counts)
         if log is not None:
-            log.append(dict(rep=rep, gain=float(g), applied=int(nmv),
-                            raced=bool(race), kernel=int(kt)))
-    return parts, (hits, phits)
+            log_rep(log, rep, g, counts, raced=bool(race))
+    return parts, level_counters(reps)
 
 
 @functools.lru_cache(maxsize=None)

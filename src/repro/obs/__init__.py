@@ -8,12 +8,14 @@ telemetry on/off is bit-identical — see docs/observability.md):
   snapshot/reset, JSONL + Prometheus export, and the ``--metrics-json``
   dump format (`dump_json` / `PeriodicDumper`).
 * `repro.obs.trace` — ``span(name, **attrs)`` wall-time span tree with
-  device-drain discipline (``sp.sync``), `jax.profiler.TraceAnnotation` /
-  `named_scope` alignment, and Perfetto/Chrome export under
-  ``REPRO_TRACE_DIR``.
+  device-drain discipline (``sp.sync``) and `jax.profiler.TraceAnnotation`
+  alignment (a profiler trace holds the spans on the device's clock);
+  ``device_sync(value, what)``, the span every blocking host read of the
+  partitioner runs in.
 * `repro.obs.vcycle` — per-level `LevelStats` (structure, capacity
-  occupancy, kernel path, connectivity/balance/distinct-incidence slack)
-  assembled by the partitioner drivers onto `PartitionResult.level_stats`.
+  occupancy, kernel path, refinement moves proposed / applied and no-op
+  repetitions, connectivity/balance/distinct-incidence slack) assembled by
+  the partitioner entry points onto `PartitionResult.level_stats`.
 """
 from repro.obs import metrics, trace, vcycle  # noqa: F401
 from repro.obs.metrics import (  # noqa: F401
@@ -25,5 +27,5 @@ from repro.obs.metrics import (  # noqa: F401
     gauge,
     observe,
 )
-from repro.obs.trace import span  # noqa: F401
+from repro.obs.trace import device_sync, span  # noqa: F401
 from repro.obs.vcycle import LevelStats  # noqa: F401

@@ -8,19 +8,21 @@ drivers block at every phase tail) or the caller hands the span its output
 value via ``sp.sync(x)`` and the exit path runs ``jax.block_until_ready``
 on it before reading the clock.
 
-Each span body is additionally wrapped in ``jax.profiler.TraceAnnotation``
-and ``jax.named_scope``, so host spans line up with device TraceMe rows in
-an XLA profile and any tracing that happens inside the span scopes its HLO
-op names.
+Each span body is additionally wrapped in ``jax.profiler.TraceAnnotation``,
+so a ``jax.profiler`` trace holds every span on the device trace's clock,
+beside the device rows. Spans enter no ``jax.named_scope``: the device op
+names of a jitted step come from the scopes inside the step alone, not
+from whichever span was open when it was first traced.
+
+``device_sync(value, what)`` is the one way the partitioner waits on the
+device: it fetches (or blocks on) a device value inside a ``device_sync``
+span whose ``what`` attribute names the read, so every blocking host read
+of a solve is timed and counted.
 
 Span exit also observes ``span.<name>.s`` into the default metrics registry
 (`repro.obs.metrics.REGISTRY`), which is how ``--metrics-json`` dumps carry
 per-phase timings; ``aggregate()`` returns per-name count/total/self-time
 rollups from the retained trees.
-
-Perfetto / chrome://tracing export is off unless ``REPRO_TRACE_DIR`` is set:
-every completed *root* span then appends its subtree to
-``<dir>/trace-<pid>.trace.json`` (Chrome trace "X" events, microseconds).
 
 Everything here is host-side Python; spans never touch traced values (a
 ``span()`` inside a jitted function would record trace time, not run time —
@@ -31,8 +33,6 @@ from __future__ import annotations
 import contextlib
 import collections
 import dataclasses
-import json
-import os
 import threading
 import time
 
@@ -47,7 +47,6 @@ MAX_ROOTS = 64
 _tls = threading.local()
 _lock = threading.Lock()
 _roots: collections.deque = collections.deque(maxlen=MAX_ROOTS)
-_trace_files: dict[str, bool] = {}  # path -> header written
 
 
 @dataclasses.dataclass
@@ -114,9 +113,7 @@ def span(name: str, **attrs):
     st = _stack()
     st.append(sp)
     ann = jax.profiler.TraceAnnotation(name)
-    scope = jax.named_scope(name)
     ann.__enter__()
-    scope.__enter__()
     sp.t0 = time.perf_counter()
     try:
         yield sp
@@ -125,7 +122,6 @@ def span(name: str, **attrs):
             jax.block_until_ready(sp._sync)
             sp._sync = None
         sp.t1 = time.perf_counter()
-        scope.__exit__(None, None, None)
         ann.__exit__(None, None, None)
         st.pop()
         if st:
@@ -133,9 +129,19 @@ def span(name: str, **attrs):
         else:
             with _lock:
                 _roots.append(sp)
-            _maybe_emit_chrome(sp)
         from repro.obs import metrics
         metrics.observe(f"span.{name}.s", sp.duration, buckets=SPAN_BUCKETS)
+
+
+def device_sync(value, what: str, read=None):
+    """Wait for the device inside a ``device_sync`` span (attribute
+    ``what``) and return what the host got: ``jax.device_get(value)`` by
+    default, else ``read(value)`` (``jax.block_until_ready`` to block
+    without a copy, or a function that reads device arrays itself)."""
+    import jax
+
+    with span("device_sync", what=what):
+        return (read or jax.device_get)(value)
 
 
 def roots() -> list:
@@ -175,37 +181,3 @@ def aggregate() -> list:
         walk(root)
     return [dict(name=n, count=c, total_s=t, self_s=s)
             for n, (c, t, s) in sorted(acc.items())]
-
-
-# ------------------------------------------------------------ chrome trace
-def _maybe_emit_chrome(root: Span) -> None:
-    tdir = os.environ.get("REPRO_TRACE_DIR")
-    if not tdir:
-        return
-    try:
-        os.makedirs(tdir, exist_ok=True)
-        path = os.path.join(tdir, f"trace-{os.getpid()}.trace.json")
-        events = []
-
-        def walk(sp: Span) -> None:
-            events.append(dict(
-                name=sp.name, ph="X", ts=sp.t0 * 1e6,
-                dur=max(sp.duration, 0.0) * 1e6, pid=os.getpid(),
-                tid=threading.get_ident() % 2 ** 31,
-                args={k: str(v) for k, v in sp.attrs.items()}))
-            for c in sp.children:
-                walk(c)
-
-        walk(root)
-        with _lock:
-            fresh = not _trace_files.get(path)
-            _trace_files[path] = True
-        # chrome trace JSON-array format tolerates a missing close bracket,
-        # so appending root subtrees keeps every dump loadable
-        with open(path, "a") as f:
-            if fresh:
-                f.write("[\n")
-            for ev in events:
-                f.write(json.dumps(ev) + ",\n")
-    except OSError:  # tracing must never take the solve down
-        pass

@@ -15,6 +15,9 @@ Two halves, matched to where the drivers already have the data:
   the same [kcap, Ecap] incidence counting the refiner itself uses) and the
   driver fetches them *once*, batched with the kernel-hit readback it
   already does after the last level — no new syncs on the hot path.
+* **refinement counters** (moves proposed and applied, repetitions that
+  applied no move) are summed on the device per level from the counts
+  every `refine_step` already returns, and ride the same readback — free.
 
 Telemetry never writes into the solve: `quality_scalars` only reads
 ``(d, parts)``, so collect_stats on/off is bit-identical (tested).
@@ -48,6 +51,9 @@ class LevelStats:
     kernel_coarsen: int | None = None     # 0/1 Pallas path taken
     # refinement-side (every level incl. the coarsest)
     kernel_refine: int | None = None      # kernel reps (0..theta)
+    moves_proposed: int | None = None     # summed over the theta reps
+    moves_applied: int | None = None      # <= moves_proposed
+    noop_reps: int | None = None          # reps that applied no move
     connectivity: float | None = None     # of the level's refined partition
     cut_net: float | None = None
     max_size: int | None = None
@@ -104,8 +110,8 @@ def quality_scalars(d, parts, caps, kcap: int, omega, delta) -> dict | None:
 def assemble(coarsen_meta: list[dict], refine_meta: dict[int, dict]
              ) -> list[LevelStats]:
     """Zip the coarsening loop's per-level structural records with the
-    refinement loop's per-level records (kernel hits + fetched quality
-    scalars) into the finest-first `LevelStats` list on
+    refinement loop's per-level records (kernel hits, move counters and
+    fetched quality scalars) into the finest-first `LevelStats` list on
     `PartitionResult.level_stats`."""
     n_levels = len(coarsen_meta)
     out = []
@@ -126,7 +132,10 @@ def assemble(coarsen_meta: list[dict], refine_meta: dict[int, dict]
             pair_occupancy=m.get("pair_occupancy"),
             nbr_occupancy=m.get("nbr_occupancy"),
             kernel_coarsen=m.get("kernel_coarsen"),
-            kernel_refine=r.get("kernel_refine"),
+            kernel_refine=r.get("kernel_hits"),
+            moves_proposed=r.get("moves_proposed"),
+            moves_applied=r.get("moves_applied"),
+            noop_reps=r.get("noop_reps"),
             connectivity=(float(q["connectivity"])
                           if "connectivity" in q else None),
             cut_net=float(q["cut_net"]) if "cut_net" in q else None,

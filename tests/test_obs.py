@@ -173,18 +173,28 @@ def test_span_roots_bounded():
     assert otrace.roots()[-1].attrs["i"] == otrace.MAX_ROOTS + 9
 
 
-def test_span_chrome_trace_export(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path))
-    with otrace.span("traced_root"):
-        with otrace.span("traced_child"):
+def test_device_sync_span_times_the_read():
+    x = jax.numpy.arange(8) * 3
+    with otrace.span("outer") as sp:
+        got = otrace.device_sync(x, "probe")
+        same = otrace.device_sync(x, "probe_block", jax.block_until_ready)
+    assert isinstance(got, np.ndarray)
+    np.testing.assert_array_equal(got, np.arange(8) * 3)
+    assert same is x
+    assert [(c.name, c.attrs) for c in sp.children] == [
+        ("device_sync", dict(what="probe")),
+        ("device_sync", dict(what="probe_block"))]
+
+
+def test_span_enters_no_named_scope(monkeypatch):
+    """Host spans leave op names to the scopes inside the jitted steps."""
+    def refuse(name):
+        raise AssertionError(f"span entered named_scope({name!r})")
+
+    monkeypatch.setattr(jax, "named_scope", refuse)
+    with otrace.span("no_scope_outer"):
+        with otrace.span("no_scope_inner"):
             pass
-    (path,) = tmp_path.glob("trace-*.trace.json")
-    text = path.read_text()
-    # chrome trace array format tolerates the missing close bracket
-    events = json.loads(text.rstrip().rstrip(",") + "]")
-    names = [e["name"] for e in events]
-    assert "traced_root" in names and "traced_child" in names
-    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in events)
 
 
 # ------------------------------------------------------------------ watchdog
@@ -299,6 +309,150 @@ def test_kway_timings_and_level_stats():
     assert ls[0].connectivity == pytest.approx(res.connectivity)
     # Delta is +inf in k-way mode: inbound slack still finite/meaningful
     assert ls[0].max_inbound is not None
+
+
+def _syncs(root):
+    out = []
+
+    def walk(sp):
+        if sp.name == "device_sync":
+            out.append(sp.attrs["what"])
+        for c in sp.children:
+            walk(c)
+    walk(root)
+    return out
+
+
+def test_partition_device_syncs_match_the_code():
+    """Every blocking host read of a solve is one ``device_sync`` span, in
+    the order a solve makes them: per coarsening-loop test one node
+    count, per coarsening level entered one batched scalar read, then the
+    coarsening tail, the coarsest pair audit, its three structure reads,
+    the refinement tail, the one batched refinement readback and the
+    parts."""
+    res = partition(HG, omega=OM, delta=DL, theta=4)
+    root = otrace.last_root("partition")
+    coarsen = root.find("coarsen")
+    entered = [c for c in coarsen.children if c.name == "coarsen_level"]
+    assert len(entered) in (res.n_levels, res.n_levels + 1)  # + a break
+    loop = []
+    for lvl in range(len(entered)):
+        loop += ["coarsen_nodes", "level_scalars"]
+    if len(entered) == res.n_levels:   # stopped by the loop test
+        loop.append("coarsen_nodes")
+    expect = loop + ["coarsen_tail", "pair_count", "coarsest_nodes",
+                     "coarsest_edges", "coarsest_pins", "refine_tail",
+                     "refine_readback", "parts"]
+    assert _syncs(root) == expect
+    assert len(expect) == 2 * res.n_levels + 9 + (len(entered)
+                                                  - res.n_levels)
+    assert obs_metrics.REGISTRY.snapshot()["histograms"][
+        "span.device_sync.s"]
+
+
+def test_refine_from_device_syncs():
+    res = partition(HG, omega=OM, delta=DL, theta=4)
+    from repro.core.partitioner import refine_from
+    refine_from(HG, res.parts, OM, DL, theta=4)
+    assert _syncs(otrace.last_root("partition")) == [
+        "refine_tail", "refine_readback", "parts"]
+
+
+@pytest.mark.parametrize("entry", ["partition", "refine_from", "kway"])
+def test_setup_blocks_on_its_upload(entry, monkeypatch):
+    """The upload drains inside the ``setup`` span, so it is timed as
+    set-up and not in the first coarsening read."""
+    from repro.core.kway import partition_kway
+    from repro.core.partitioner import refine_from
+
+    seen = []
+    real = jax.block_until_ready
+
+    def recording(x):
+        st = otrace._stack()
+        seen.append((st[-1].name if st else None,
+                     {type(v).__name__ for v in jax.tree.leaves(x)}))
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", recording)
+    if entry == "partition":
+        partition(HG, omega=OM, delta=DL, theta=2)
+    elif entry == "refine_from":
+        refine_from(HG, np.arange(HG.n_nodes) % 4, OM, DL, theta=2)
+    else:
+        partition_kway(HG, k=4, theta=2)
+    setup = [leaves for name, leaves in seen if name == "setup"]
+    assert len(setup) == 1 and setup[0] == {"ArrayImpl"}
+
+
+def test_refine_counters_match_the_rep_log():
+    """The per-level counters summed on the device equal a recount of
+    ``collect_log``'s per-repetition reads, level by level."""
+    theta = 4
+    res = partition(HG, omega=OM, delta=DL, theta=theta, collect_log=True)
+    reps = [e for e in res.level_log if "rep" in e]
+    assert len(reps) == theta * (res.n_levels + 1)
+    root = otrace.last_root("partition")
+    spans = [s for s in root.find("refine").children
+             if s.name == "refine_level"]
+    by_level = {s.level: s for s in res.level_stats}
+    for i, sp in enumerate(spans):        # coarsest level first
+        log = reps[i * theta:(i + 1) * theta]
+        ls = by_level[sp.attrs["level"]]
+        want = dict(moves_proposed=sum(e["proposed"] for e in log),
+                    moves_applied=sum(e["applied"] for e in log),
+                    noop_reps=sum(e["applied"] == 0 for e in log))
+        assert {k: getattr(ls, k) for k in want} == want
+        assert {k: sp.attrs[k] for k in want} == want
+        assert sp.attrs["reps"] == theta
+        assert ls.kernel_refine == sum(e["kernel"] for e in log)
+        assert 0 <= ls.moves_applied <= ls.moves_proposed
+    assert sum(s.moves_applied for s in res.level_stats) > 0
+
+
+def test_collect_log_parity_bit_exact():
+    base = partition(HG, omega=OM, delta=DL, theta=4)
+    logged = partition(HG, omega=OM, delta=DL, theta=4, collect_log=True)
+    np.testing.assert_array_equal(base.parts, logged.parts)
+    assert [s.to_dict() for s in base.level_stats] == \
+        [s.to_dict() for s in logged.level_stats]
+
+
+def _step_text(which):
+    """A step's lowered text with locations, at the test graph's shapes."""
+    import jax.numpy as jnp
+
+    from repro.core import coarsen, contract, refine
+    from repro.core.hypergraph import Caps, device_from_host
+
+    caps = Caps.for_host(HG)
+    d = device_from_host(HG, caps)
+    if which == "refine_step":
+        params = refine.RefineParams(omega=OM, delta=DL, theta=2)
+        lowered = refine.refine_step.lower(
+            d, jnp.zeros((caps.n,), jnp.int32), jnp.int32(4), caps, 4,
+            params, True)
+    elif which == "coarsen_step":
+        lowered = coarsen.coarsen_step.lower(
+            d, caps, coarsen.CoarsenParams(omega=OM, delta=DL))
+    else:
+        lowered = contract.contract.lower(
+            d, jnp.full((caps.n,), -1, jnp.int32), caps)
+    return lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("which,scopes", [
+    ("refine_step", ["pins", "propose", "chains", "inseq_gains", "events"]),
+    ("coarsen_step", ["pairs", "neighbors", "propose", "matching"]),
+    ("contract", ["clusters", "pins", "incidence"]),
+])
+def test_steps_name_their_phases(which, scopes):
+    text = _step_text(which)
+    for scope in scopes:
+        assert f'"jit({which})/{scope}/' in text, scope
+    # a host span open while tracing leaves no name in the step
+    with otrace.span("host_span_probe"):
+        assert "host_span_probe" not in _step_text(which)
 
 
 @pytest.mark.slow

@@ -152,8 +152,8 @@ def test_refine_step_monotone_and_valid():
     conn0 = metrics.connectivity(hg, parts0)
     p = parts
     for rep in range(3):
-        p, g, nmv, _, _ = R.refine_step(d, p, jnp.int32(K), caps, kcap,
-                                        params, enforce_size=True)
+        p, g, _ = R.refine_step(d, p, jnp.int32(K), caps, kcap, params,
+                                enforce_size=True)
     parts1 = np.asarray(p)[: hg.n_nodes]
     conn1 = metrics.connectivity(hg, parts1)
     assert conn1 <= conn0 + 1e-6
