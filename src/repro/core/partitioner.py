@@ -48,8 +48,9 @@ class PartitionResult:
     level_log: list
     # per-level Pallas dispatch coverage (empty when use_kernels=False):
     #   "coarsen": [0/1 per coarsening level, finest first]
-    #   "refine":  [gains-kernel reps (0..theta) per refined level, finest
-    #               first; the last entry is the coarsest level]
+    #   "refine":  [gains-kernel reps (0..theta, of those that ran) per
+    #               refined level, finest first; the last entry is the
+    #               coarsest level]
     #   "pins":    [pins-count-kernel reps per refined level, same layout]
     kernel_path: dict = dataclasses.field(default_factory=dict)
     # per-level telemetry (repro.obs.vcycle.LevelStats, finest first;
@@ -110,9 +111,12 @@ def make_refine_fn(k, kcap: int, rparams: RefineParams, rlog,
     mesh-raced/sharded `dist.partition.refine_level` with one (seed offset
     by level so replica tie-break permutations decorrelate across levels).
     Returns `fn(d, parts, caps, level) -> (parts, counters)` — the level's
-    int32[6] device vector in `refine.LEVEL_COUNTERS` order (Pallas-branch
+    int32[7] device vector in `refine.LEVEL_COUNTERS` order (Pallas-branch
     repetitions of the gains / pins dispatch, moves proposed and applied,
-    repetitions that applied no move, theta)."""
+    repetitions skipped, repetitions that applied no move, theta). Theta
+    repetitions are dispatched; without a plan each half-level stops at its
+    first repetition that applies no move (`refine.refine_level`), on a
+    mesh every repetition runs (racing makes each one differ)."""
     if plan is None:
         def _refine(d_, parts_, caps_, lvl_):
             return refine_level(d_, parts_, k, caps_, kcap, rparams, rlog)
@@ -195,9 +199,9 @@ def run_refine_loop(d, parts, caps: Caps, levels, gammas, _refine,
     hits lists the per-level Pallas-branch repetition counts (gains / pins
     dispatch) for ``PartitionResult.kernel_path``. Each ``refine_level``
     span is annotated with its ``moves_proposed``, ``moves_applied``,
-    ``noop_reps`` and ``reps`` once the readback has them. With
-    ``levels=[]`` (warm start) this is a single-level refine of ``d`` — no
-    projection, no coarsening."""
+    ``skipped_reps``, ``noop_reps`` and ``reps`` once the readback has
+    them. With ``levels=[]`` (warm start) this is a single-level refine of
+    ``d`` — no projection, no coarsening."""
     quality_dev: dict = {}
     counters_dev: dict = {}
     level_spans: dict = {}

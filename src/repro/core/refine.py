@@ -505,10 +505,13 @@ def events_validity(d: DeviceHypergraph, parts: jax.Array,
 # ---------------------------------------------------------------------------
 # 6. one refinement repetition + level driver
 # ---------------------------------------------------------------------------
-# Per-repetition counts (int32[4], the step's third output) and per-level
-# counters (int32[6], `level_counters`), by position.
-REP_COUNTS = ("kernel_hits", "pins_hits", "moves_proposed", "moves_applied")
-LEVEL_COUNTERS = REP_COUNTS + ("noop_reps", "reps")
+# Per-repetition counts (int32[5], the step's third output) and per-level
+# counters (int32[7], `level_counters`), by position. The first five level
+# counters are the sums of the repetition counts; ``skipped`` (0/1) becomes
+# ``skipped_reps`` there.
+REP_COUNTS = ("kernel_hits", "pins_hits", "moves_proposed", "moves_applied",
+              "skipped")
+LEVEL_COUNTERS = REP_COUNTS[:-1] + ("skipped_reps", "noop_reps", "reps")
 
 
 def refine_step_impl(d: DeviceHypergraph, parts: jax.Array,
@@ -522,10 +525,11 @@ def refine_step_impl(d: DeviceHypergraph, parts: jax.Array,
     (``ctx`` shards the pins/pairs pipelines, ``tie_rank`` diversifies
     replicas).
 
-    Returns ``(parts_new, applied_gain, counts)``: ``counts`` is int32[4]
+    Returns ``(parts_new, applied_gain, counts)``: ``counts`` is int32[5]
     in `REP_COUNTS` order — whether the gains and the pins dispatch took
     the Pallas branch (0/1 each), the moves proposed (nodes with a
-    destination) and the moves applied. Each phase runs under a
+    destination), the moves applied, and 0 (the repetition ran; only
+    ``refine_step`` skips). Each phase runs under a
     ``jax.named_scope`` of its own, so its device ops carry a stable name."""
     with jax.named_scope("pins"):
         if params.use_kernels:
@@ -558,25 +562,52 @@ def refine_step_impl(d: DeviceHypergraph, parts: jax.Array,
                           parts)
     counts = jnp.stack([kernel_taken, pins_taken,
                         jnp.sum((move_to >= 0).astype(jnp.int32)),
-                        jnp.sum(apply_mask.astype(jnp.int32))])
+                        jnp.sum(apply_mask.astype(jnp.int32)),
+                        jnp.int32(0)])
     return parts_new, applied_gain, counts
 
 
 @partial(jax.jit, static_argnames=("caps", "kcap", "params"))
 def refine_step(d: DeviceHypergraph, parts: jax.Array, n_parts: jax.Array,
                 caps: Caps, kcap: int, params: RefineParams,
-                enforce_size):
+                enforce_size, prev_counts: jax.Array | None = None,
+                fresh=True):
     """One jitted repetition. ``enforce_size`` is traced, so both halves of
-    a level's theta repetitions share one executable."""
-    return refine_step_impl(d, parts, n_parts, caps, kcap, params,
-                            jnp.asarray(enforce_size, bool))
+    a level's theta repetitions share one executable.
+
+    With ``prev_counts`` (the previous repetition's `REP_COUNTS` vector,
+    traced like ``fresh``) the repetition runs only when ``fresh`` (the
+    first of its half-level) or when the previous one applied a move; else
+    it returns ``parts`` unchanged, gain 0 and counts that mark it skipped.
+    The skip is exact: a repetition is a function of ``(d, parts, n_parts,
+    enforce_size)``, and one that applied no move left ``parts`` as it
+    was. Without ``prev_counts`` every call runs."""
+    enforce_size = jnp.asarray(enforce_size, bool)
+
+    def run():
+        return refine_step_impl(d, parts, n_parts, caps, kcap, params,
+                                enforce_size)
+
+    if prev_counts is None:
+        return run()
+
+    def skip():
+        return (parts, jnp.float32(0),
+                jnp.zeros(len(REP_COUNTS), jnp.int32)
+                .at[REP_COUNTS.index("skipped")].set(1))
+
+    go = (jnp.asarray(fresh, bool)
+          | (prev_counts[REP_COUNTS.index("moves_applied")] > 0))
+    return jax.lax.cond(go, run, skip)
 
 
 @jax.jit
 def level_counters(rep_counts: list) -> jax.Array:
-    """One level's counters, int32[6] in `LEVEL_COUNTERS` order, from its
-    repetitions' `REP_COUNTS` vectors: their sums, the repetitions that
-    applied no move, and the repetition count. One dispatch per level."""
+    """One level's counters, int32[7] in `LEVEL_COUNTERS` order, from its
+    repetitions' `REP_COUNTS` vectors: their sums (skipped repetitions
+    count 0 in the first four), the repetitions that applied no move
+    (skipped ones included), and the repetition count. One dispatch per
+    level."""
     c = jnp.stack(rep_counts)
     applied = c[:, REP_COUNTS.index("moves_applied")]
     return jnp.concatenate([jnp.sum(c, axis=0),
@@ -593,24 +624,31 @@ def log_rep(log: list, rep: int, gain, counts, **extra) -> None:
     log.append(dict(rep=rep, gain=float(g),
                     applied=int(c[REP_COUNTS.index("moves_applied")]),
                     proposed=int(c[REP_COUNTS.index("moves_proposed")]),
-                    kernel=int(c[REP_COUNTS.index("kernel_hits")]), **extra))
+                    kernel=int(c[REP_COUNTS.index("kernel_hits")]),
+                    skipped=int(c[REP_COUNTS.index("skipped")]), **extra))
 
 
 def refine_level(d: DeviceHypergraph, parts: jax.Array, n_parts,
                  caps: Caps, kcap: int, params: RefineParams,
                  log: list | None = None):
-    """Theta repetitions; first half may propose size-violating moves.
-    Returns ``(parts, counters)`` — ``counters`` the level's int32[6]
+    """Theta dispatched repetitions in two halves; the first half may
+    propose size-violating moves. Each half stops at its first repetition
+    that applies no move: the ones after it are skipped on the device
+    (`refine_step`), bit-identical to running them, with no host sync.
+    Returns ``(parts, counters)`` — ``counters`` the level's int32[7]
     device vector in `LEVEL_COUNTERS` order (`level_counters`): the
     repetitions whose gains / pins dispatch took the Pallas branch, the
-    moves proposed and applied, the repetitions that applied none, and
-    theta. No host sync unless ``log`` is given."""
+    moves proposed and applied, the repetitions skipped, those that
+    applied no move (skipped ones included), and theta. No host sync
+    unless ``log`` is given."""
     n_parts = jnp.asarray(n_parts, jnp.int32)
+    half = params.theta // 2
+    counts = jnp.zeros(len(REP_COUNTS), jnp.int32)
     reps = []
     for rep in range(params.theta):
-        enforce = rep >= params.theta // 2
         parts, g, counts = refine_step(d, parts, n_parts, caps, kcap,
-                                       params, enforce)
+                                       params, rep >= half, counts,
+                                       rep in (0, half))
         reps.append(counts)
         if log is not None:
             log_rep(log, rep, g, counts)

@@ -15,9 +15,10 @@ Two halves, matched to where the drivers already have the data:
   the same [kcap, Ecap] incidence counting the refiner itself uses) and the
   driver fetches them *once*, batched with the kernel-hit readback it
   already does after the last level — no new syncs on the hot path.
-* **refinement counters** (moves proposed and applied, repetitions that
-  applied no move) are summed on the device per level from the counts
-  every `refine_step` already returns, and ride the same readback — free.
+* **refinement counters** (moves proposed and applied, repetitions
+  skipped and repetitions that applied no move) are summed on the device
+  per level from the counts every `refine_step` already returns, and ride
+  the same readback — free.
 
 Telemetry never writes into the solve: `quality_scalars` only reads
 ``(d, parts)``, so collect_stats on/off is bit-identical (tested).
@@ -50,9 +51,10 @@ class LevelStats:
     nbr_occupancy: float | None = None    # nbr_entries / caps.nbrs
     kernel_coarsen: int | None = None     # 0/1 Pallas path taken
     # refinement-side (every level incl. the coarsest)
-    kernel_refine: int | None = None      # kernel reps (0..theta)
-    moves_proposed: int | None = None     # summed over the theta reps
+    kernel_refine: int | None = None      # kernel reps (0..theta, run ones)
+    moves_proposed: int | None = None     # summed over the reps that ran
     moves_applied: int | None = None      # <= moves_proposed
+    skipped_reps: int | None = None       # reps after a half's first no-op
     noop_reps: int | None = None          # reps that applied no move
     connectivity: float | None = None     # of the level's refined partition
     cut_net: float | None = None
@@ -135,6 +137,7 @@ def assemble(coarsen_meta: list[dict], refine_meta: dict[int, dict]
             kernel_refine=r.get("kernel_hits"),
             moves_proposed=r.get("moves_proposed"),
             moves_applied=r.get("moves_applied"),
+            skipped_reps=r.get("skipped_reps"),
             noop_reps=r.get("noop_reps"),
             connectivity=(float(q["connectivity"])
                           if "connectivity" in q else None),

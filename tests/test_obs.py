@@ -401,6 +401,7 @@ def test_refine_counters_match_the_rep_log():
         ls = by_level[sp.attrs["level"]]
         want = dict(moves_proposed=sum(e["proposed"] for e in log),
                     moves_applied=sum(e["applied"] for e in log),
+                    skipped_reps=sum(e["skipped"] for e in log),
                     noop_reps=sum(e["applied"] == 0 for e in log))
         assert {k: getattr(ls, k) for k in want} == want
         assert {k: sp.attrs[k] for k in want} == want
@@ -408,6 +409,7 @@ def test_refine_counters_match_the_rep_log():
         assert ls.kernel_refine == sum(e["kernel"] for e in log)
         assert 0 <= ls.moves_applied <= ls.moves_proposed
     assert sum(s.moves_applied for s in res.level_stats) > 0
+    assert sum(s.skipped_reps for s in res.level_stats) > 0
 
 
 def test_collect_log_parity_bit_exact():
@@ -439,6 +441,26 @@ def _step_text(which):
         lowered = contract.contract.lower(
             d, jnp.full((caps.n,), -1, jnp.int32), caps)
     return lowered.as_text(debug_info=True)
+
+
+def test_gated_refine_step_names_its_phases_inside_the_run_branch():
+    """`refine_level` calls the step with the previous counts, which puts
+    the repetition under a cond: the phases keep their scopes, inside the
+    branch that runs it."""
+    import jax.numpy as jnp
+
+    from repro.core import refine
+    from repro.core.hypergraph import Caps, device_from_host
+
+    caps = Caps.for_host(HG)
+    text = refine.refine_step.lower(
+        device_from_host(HG, caps), jnp.zeros((caps.n,), jnp.int32),
+        jnp.int32(4), caps, 4, refine.RefineParams(omega=OM, delta=DL,
+                                                   theta=2),
+        True, jnp.zeros(len(refine.REP_COUNTS), jnp.int32),
+        True).as_text(debug_info=True)
+    for scope in ["pins", "propose", "chains", "inseq_gains", "events"]:
+        assert f'"jit(refine_step)/cond/branch_1_fun/{scope}/' in text, scope
 
 
 @pytest.mark.parametrize("which,scopes", [

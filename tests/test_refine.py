@@ -1,6 +1,7 @@
 """Refinement: pins matrix, isolation gains, in-sequence gains (exact vs
 brute force AND vs a literal Eq. 14/15 oracle), events-based selection vs
 step-by-step simulation (paper Sec. VI)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -157,3 +158,45 @@ def test_refine_step_monotone_and_valid():
     parts1 = np.asarray(p)[: hg.n_nodes]
     conn1 = metrics.connectivity(hg, parts1)
     assert conn1 <= conn0 + 1e-6
+
+
+@pytest.mark.parametrize("seed,omega,delta", [
+    (0, 14, 40),    # the second half applies once, then stops
+    (1, 14, 40),    # nothing applies: each half stops at its first rep
+    (2, 24, 80),    # the first half applies once, the second all through
+    (3, 16, 60),    # the second half applies four times before its no-op
+])
+def test_refine_level_early_exit_is_exact(seed, omega, delta):
+    """`refine_level` skips each half-level's repetitions after its first
+    no-op, and still returns the parts of a plain loop of every
+    repetition, bit for bit."""
+    n, K, kcap, theta = 48, 5, 8, 12
+    rng = np.random.default_rng(seed)
+    hg = generate.random_kuniform(n_nodes=n, n_edges=72, k=4, seed=seed,
+                                  weighted=True)
+    caps = H.Caps.for_host(hg)
+    d = H.device_from_host(hg, caps)
+    parts = jnp.asarray(np.pad(rng.integers(0, K, size=n).astype(np.int32),
+                               (0, caps.n - n)))
+    params = R.RefineParams(omega=omega, delta=delta, theta=theta)
+    plain_step = jax.jit(R.refine_step_impl,
+                         static_argnames=("caps", "kcap", "params"))
+    want = parts
+    for rep in range(theta):
+        want, _, _ = plain_step(d, want, jnp.int32(K), caps=caps, kcap=kcap,
+                                params=params,
+                                enforce_size=jnp.asarray(rep >= theta // 2))
+    log = []
+    got, counters = R.refine_level(d, parts, K, caps, kcap, params, log=log)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    cnt = dict(zip(R.LEVEL_COUNTERS, np.asarray(counters).tolist()))
+    for e in log:
+        fresh = e["rep"] in (0, theta // 2)
+        after_noop = not fresh and log[e["rep"] - 1]["applied"] == 0
+        assert e["skipped"] == after_noop, e
+        if e["skipped"]:
+            assert (e["applied"], e["proposed"], e["gain"]) == (0, 0, 0.0)
+    ran = sum(not e["skipped"] for e in log)
+    assert cnt["skipped_reps"] == theta - ran
+    assert cnt["noop_reps"] == sum(e["applied"] == 0 for e in log)
+    assert cnt["reps"] == theta
