@@ -83,6 +83,13 @@ class Proposals:
         default_factory=lambda: jnp.int32(0))  # scalar
     n_nbr_entries: jax.Array = dataclasses.field(
         default_factory=lambda: jnp.int32(0))  # scalar
+    # live neighbour slots refused as merge candidates, each counted for
+    # the first limit it breaks: merged size above Omega, else inbound
+    # union above Delta (which limit binds the coarsening; telemetry only)
+    n_refused_omega: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))  # scalar
+    n_refused_delta: jax.Array = dataclasses.field(
+        default_factory=lambda: jnp.int32(0))  # scalar
     # 1 iff the use_kernels dispatch took the Pallas branch (0 on the
     # segment path or with use_kernels=False) — surfaces silent fallbacks
     # to tests/benchmarks via `PartitionResult.kernel_path`
@@ -153,6 +160,12 @@ def propose(d: DeviceHypergraph, nbrs: Neighborhoods, pairs: PairExpansion,
     union = d.node_nin[owner_safe] + d.node_nin[m_safe] - inter
     inbound_ok = union <= params.delta
     valid_slot = entry_live & size_ok & inbound_ok
+    # which limit refuses each live slot, the first it breaks; the slot
+    # arrays are replicated on every shard, so these sums are already the
+    # global counts and need no collective
+    n_refused_omega = jnp.sum(entry_live & ~size_ok, dtype=jnp.int32)
+    n_refused_delta = jnp.sum(entry_live & size_ok & ~inbound_ok,
+                              dtype=jnp.int32)
 
     value = jnp.where(valid_slot, eta_n, NEG)
 
@@ -181,6 +194,8 @@ def propose(d: DeviceHypergraph, nbrs: Neighborhoods, pairs: PairExpansion,
     return Proposals(cand_ids=jnp.stack(cand_ids),
                      cand_scores=jnp.stack(cand_scores),
                      eta=eta_n, inter=inter, valid_slot=valid_slot,
+                     n_refused_omega=n_refused_omega,
+                     n_refused_delta=n_refused_delta,
                      kernel_path_taken=kernel_taken)
 
 

@@ -70,7 +70,8 @@ def make_coarsen_fns(cparams: CoarsenParams, plan, dist_coarsen: bool = True,
                      compensated: bool = False):
     """Per-level coarsening dispatchers shared by `partition` and
     `kway.partition_kway`: returns `(coarsen(d, caps) -> (match, n_pairs,
-    (n_pairs_live, n_nbr_entries, kernel_path_taken)),
+    (n_pairs_live, n_nbr_entries, kernel_path_taken, n_refused_omega,
+    n_refused_delta)),
     contract(d, match, caps) -> (d2, gamma))`. With a `Plan` (and
     `dist_coarsen`), both run on the mesh via `dist.partition.coarsen_level`
     / `contract_level` — bit-exact with the single-device pair at matching
@@ -82,13 +83,16 @@ def make_coarsen_fns(cparams: CoarsenParams, plan, dist_coarsen: bool = True,
 
     Both dispatchers return the same shapes in either mode; `_coarsen`'s
     trailing diagnostics feed the drivers' host-side capacity-overflow
-    audit (`check_expansion_caps`) and the per-level kernel-coverage
-    accounting (`PartitionResult.kernel_path`)."""
+    audit (`check_expansion_caps`), the per-level kernel-coverage
+    accounting (`PartitionResult.kernel_path`) and the count of merge
+    candidates each limit refused."""
     if plan is None or not dist_coarsen:
         def _coarsen(d_, caps_):
             match, n_pairs, props = coarsen_step(d_, caps_, cparams)
             return match, n_pairs, (props.n_pairs_live, props.n_nbr_entries,
-                                    props.kernel_path_taken)
+                                    props.kernel_path_taken,
+                                    props.n_refused_omega,
+                                    props.n_refused_delta)
 
         def _contract(d_, match_, caps_):
             return contract(d_, match_, caps_)
@@ -134,7 +138,7 @@ def run_coarsen_loop(d, caps: Caps, target: int, max_levels: int,
                      _coarsen, _contract, log: list | None,
                      shrink: bool = False):
     """Host-driven audited coarsening loop shared by `partition` and
-    `kway.partition_kway`: per level, one batched device sync for the four
+    `kway.partition_kway`: per level, one batched device sync for its
     scalars, a `check_expansion_caps` overflow audit BEFORE trusting the
     matches (the device pipelines drop out-of-capacity lanes silently), stop
     on `n_pairs == 0` or `target`. Returns
@@ -142,21 +146,26 @@ def run_coarsen_loop(d, caps: Caps, target: int, max_levels: int,
     ``levels`` a list of ``(d, caps)`` per retained level (caps varies only
     under ``shrink``, the pow2 re-bucketing mode) and ``coarsen_meta`` one
     structural-stats dict per retained level (nodes/edges/pins, live pair
-    and neighborhood counts with their capacity occupancy, kernel path) —
-    assembled from the same batched per-level sync, so telemetry adds no
-    round-trips. Blocks on the dispatch tail before returning so the
+    and neighborhood counts with their capacity occupancy, kernel path,
+    slots refused by Omega and by Delta) — assembled from the same batched
+    per-level sync, so telemetry adds no round-trips; each
+    ``coarsen_level`` span carries ``slots_live``, ``refused_omega`` and
+    ``refused_delta``. Blocks on the dispatch tail before returning so the
     caller's phase timer doesn't leak into the next phase."""
     from repro.core.hypergraph import shrink_device
 
     levels, gammas, coarsen_hits, coarsen_meta = [], [], [], []
     while (int(otrace.device_sync(d.n_nodes, "coarsen_nodes")) > target
            and len(gammas) < max_levels):
-        with otrace.span("coarsen_level", level=len(gammas)):
+        with otrace.span("coarsen_level", level=len(gammas)) as sp:
             match, n_pairs, ovf = _coarsen(d, caps)
-            (pairs_live, nbr_entries, kern_hit, n_pairs_h, nodes_h, edges_h,
-             pins_h) = (int(v) for v in otrace.device_sync(
-                 [*ovf, n_pairs, d.n_nodes, d.n_edges, d.n_pins],
-                 "level_scalars"))
+            (pairs_live, nbr_entries, kern_hit, refused_omega, refused_delta,
+             n_pairs_h, nodes_h, edges_h, pins_h) = (
+                int(v) for v in otrace.device_sync(
+                    [*ovf, n_pairs, d.n_nodes, d.n_edges, d.n_pins],
+                    "level_scalars"))
+            sp.annotate(slots_live=nbr_entries, refused_omega=refused_omega,
+                        refused_delta=refused_delta)
             check_expansion_caps(caps, pairs_live, nbr_entries)
             if n_pairs_h == 0:
                 break
@@ -166,7 +175,8 @@ def run_coarsen_loop(d, caps: Caps, target: int, max_levels: int,
                 pairs_live=pairs_live, nbr_entries=nbr_entries,
                 pair_occupancy=pairs_live / caps.pairs,
                 nbr_occupancy=nbr_entries / caps.nbrs,
-                kernel_coarsen=kern_hit))
+                kernel_coarsen=kern_hit, refused_omega=refused_omega,
+                refused_delta=refused_delta))
             d2, gamma = _contract(d, match, caps)
             if log is not None:
                 log.append(dict(kind="coarsen", level=len(gammas),

@@ -256,10 +256,11 @@ def _build_coarsen_step(mesh, model_axis: str | None, nshards: int,
     def body(d):
         match, n_pairs, props = coarsen_step_impl(d, caps, cparams, ctx)
         return (match, n_pairs, props.n_pairs_live, props.n_nbr_entries,
-                props.kernel_path_taken)
+                props.kernel_path_taken, props.n_refused_omega,
+                props.n_refused_delta)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=(graph_pspecs(striped),),
-                       out_specs=(P(), P(), P(), P(), P()), check_vma=False)
+                       out_specs=(P(),) * 7, check_vma=False)
     return jax.jit(fn)
 
 
@@ -302,16 +303,18 @@ def coarsen_level(d, caps: Caps, cparams: CoarsenParams, plan: Plan,
     device.)
 
     Returns ``(match, n_matched_pairs, (n_pairs_live, n_nbr_entries,
-    kernel_path_taken))`` — the first two diagnostics feed the drivers'
-    host-side capacity-overflow audit
-    (`core.hypergraph.check_expansion_caps`); the trailing flag is 1 iff
-    the pair_scores dispatch took the Pallas branch at this level."""
+    kernel_path_taken, n_refused_omega, n_refused_delta))`` — the first
+    two diagnostics feed the drivers' host-side capacity-overflow audit
+    (`core.hypergraph.check_expansion_caps`); the flag is 1 iff the
+    pair_scores dispatch took the Pallas branch at this level; the last
+    two count the neighbour slots refused by Omega and by Delta, the same
+    on every shard (the slot arrays they sum are replicated)."""
     d, striped = _graph_arg(d)
     _, model_axis, nshards = plan_axes(plan)
     step = _build_coarsen_step(plan.mesh, model_axis, nshards, caps, cparams,
                                bool(compensated), striped)
-    match, n_pairs, pairs_live, nbr_entries, kernel_hit = step(d)
-    return match, n_pairs, (pairs_live, nbr_entries, kernel_hit)
+    match, n_pairs, *diag = step(d)
+    return match, n_pairs, tuple(diag)
 
 
 def contract_level(d, match, caps: Caps, plan: Plan):

@@ -4,7 +4,8 @@ Table 2 per-level accounting as a first-class telemetry record.
 Two halves, matched to where the drivers already have the data:
 
 * **structural** side (nodes/edges/pins, pair/nbr expansion live counts and
-  capacity occupancy, kernel-vs-segment path) comes from scalars the
+  capacity occupancy, kernel-vs-segment path, merge candidates refused by
+  Omega and by Delta) comes from scalars the
   coarsening loop already syncs per level (`run_coarsen_loop` batches them
   into the one `device_get` it pays anyway for the stop/audit check) — free.
 * **quality** side (connectivity/cut of the projected partition, per-block
@@ -50,6 +51,8 @@ class LevelStats:
     pair_occupancy: float | None = None   # pairs_live / caps.pairs
     nbr_occupancy: float | None = None    # nbr_entries / caps.nbrs
     kernel_coarsen: int | None = None     # 0/1 Pallas path taken
+    refused_omega: int | None = None      # live slots over Omega merged
+    refused_delta: int | None = None      # within Omega, over Delta merged
     # refinement-side (every level incl. the coarsest)
     kernel_refine: int | None = None      # kernel reps (0..theta, run ones)
     moves_proposed: int | None = None     # summed over the reps that ran
@@ -134,6 +137,8 @@ def assemble(coarsen_meta: list[dict], refine_meta: dict[int, dict]
             pair_occupancy=m.get("pair_occupancy"),
             nbr_occupancy=m.get("nbr_occupancy"),
             kernel_coarsen=m.get("kernel_coarsen"),
+            refused_omega=m.get("refused_omega"),
+            refused_delta=m.get("refused_delta"),
             kernel_refine=r.get("kernel_hits"),
             moves_proposed=r.get("moves_proposed"),
             moves_applied=r.get("moves_applied"),

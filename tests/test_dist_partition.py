@@ -98,6 +98,9 @@ def test_coarsen_contract_level_parity():
     # overflow diagnostics agree with the single-device step and with caps
     assert int(props0.n_pairs_live) == int(ovf1[0]) <= caps.pairs
     assert int(props0.n_nbr_entries) == int(ovf1[1]) <= caps.nbrs
+    # so do the slots refused by Omega and by Delta, summed over stripes
+    assert (int(props0.n_refused_omega), int(props0.n_refused_delta)) == \
+        tuple(int(v) for v in ovf1[3:])
     d20, g0 = contract(d, m0, caps)
     d21, g1 = dp.contract_level(d, m1, caps, plan)
     assert np.array_equal(np.asarray(g0), np.asarray(g1))
@@ -170,11 +173,16 @@ _MULTIDEV = textwrap.dedent("""
         assert np.array_equal(r0.parts, r1.parts), shape
         assert r0.audit == r1.audit, shape
         assert r0.n_levels == r1.n_levels, shape  # coarsening on-mesh too
+        refused = lambda r: [(s.refused_omega, s.refused_delta)
+                             for s in r.level_stats]
+        assert refused(r0) == refused(r1), shape
+        assert any(o for o, _ in refused(r0)[:-1])  # Omega refuses some
         rs = partition(hg, omega=16, delta=64, theta=4, plan=plan,
                        race=False, shard_graph=True)
         assert np.array_equal(r0.parts, rs.parts), ("sharded", shape)
         assert r0.audit == rs.audit, ("sharded", shape)
         assert r0.n_levels == rs.n_levels, ("sharded", shape)
+        assert refused(r0) == refused(rs), ("sharded", shape)
 
     # --- sharded storage really stripes: each device holds 1/4 of the
     # pins lanes on the (2,4) mesh (replicated across the data axis)
